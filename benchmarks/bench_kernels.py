@@ -18,7 +18,10 @@ from repro.core import k2forest, k2tree
 from repro.core.k2tree import K2Meta, hybrid_ks
 from repro.kernels import ref
 
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, peaks
+
+# the analytic floors printed below are for one TPU v5e chip
+PEAK = peaks(V5E)
 
 
 def _t(fn, *a, n=5):
@@ -39,7 +42,7 @@ def run():
     t = _t(jax.jit(ref.popcount_ref), w)
     nbytes = w.size * 4 * 2
     rows.append(("popcount", t * 1e3, f"{nbytes/t/1e9:.1f} GB/s cpu; "
-                 f"tpu mem-bound floor {nbytes/HBM_BW*1e6:.1f} us"))
+                 f"v5e mem-bound floor {nbytes/PEAK.hbm_bw*1e6:.1f} us"))
 
     # k2_check: batched point queries
     meta = K2Meta(hybrid_ks(100_000))
@@ -81,7 +84,7 @@ def run():
     arena_kib = int(forest.t_words.size + forest.l_words.size) * 4 / 1024
     rows.append(("k2_scan(pallas-interp)", t_pl * 1e3,
                  f"{sq/t_pl/1e3:.1f} Kscans/s cpu; forest arena "
-                 f"{arena_kib:.0f} KiB -> VMEM-resident; "
+                 f"{arena_kib:.0f} KiB, HBM-resident; "
                  f"agrees bit-exact with jnp ref (tests/test_k2_scan.py)"))
 
     # pred_gather: SP/OP candidate-predicate gather (pruned unbounded path)
@@ -157,8 +160,8 @@ def run():
     skipped = 1 - mask.mean()
     rows.append(("block_spmm", t * 1e3,
                  f"{dense_flops/t/1e9:.1f} GFLOP/s cpu dense-equiv; mask skips "
-                 f"{skipped*100:.0f}% of tiles -> tpu compute floor "
-                 f"{dense_flops*(1-skipped)/PEAK_FLOPS_BF16*1e6:.1f} us"))
+                 f"{skipped*100:.0f}% of tiles -> v5e compute floor "
+                 f"{dense_flops*(1-skipped)/PEAK.flops_bf16*1e6:.1f} us"))
     return rows
 
 

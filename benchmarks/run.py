@@ -52,6 +52,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro import obs
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
 
     from benchmarks import (
         bench_compression, bench_dynamic, bench_joins, bench_kernels,

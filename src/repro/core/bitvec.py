@@ -22,6 +22,23 @@ import numpy as np
 
 WORD_BITS = 32
 
+# Device arrays are stored in whole 32-bit TPU tiles of (8, 128) words:
+# 2-D arenas pad rows to 8 and columns to 128, 1-D arrays pad to 1024 words
+# (a (N/128, 128) view of them is then a free bitcast).  The traversal
+# kernels DMA whole tiles from these arrays, so aligning them once at build
+# time keeps every tile read in bounds without a per-call copy.
+TILE_ROWS, TILE_COLS = 8, 128
+
+
+def round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def tile_pad_1d(a: np.ndarray) -> np.ndarray:
+    """Zero-pad a 1-D array to whole (8, 128) tiles (a multiple of 1024)."""
+    n = round_up(max(a.shape[0], 1), TILE_ROWS * TILE_COLS)
+    return np.pad(a, (0, n - a.shape[0]))
+
 
 class BitVec(NamedTuple):
     """A packed bit vector plus rank acceleration structure.

@@ -59,9 +59,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
-from repro.compat import shard_map
 from repro.core import delta as dyn
-from repro.core import joins, k2forest, patterns, predindex, query as qapi
+from repro.core import bitvec, joins, k2forest, patterns, predindex, query as qapi
 from repro.obs import cost as obs_cost
 from repro.core.k2forest import K2Forest
 from repro.core.k2tree import _compact
@@ -198,8 +197,7 @@ def _u_candidates(
     is_u_check = q.op == OP_S_ANY_O
     is_u = is_u_pair | is_u_check
     u_axis = jnp.where(q.op == OP_ANY_ANY_O, 1, 0).astype(jnp.int32)
-    u_key = jnp.maximum(jnp.where(u_axis == 1, q.o, q.s) - 1, 0)
-    u_key = jnp.where(is_u, u_key, 0)
+    u_key = jnp.where(is_u, jnp.where(u_axis == 1, q.o, q.s) - 1, 0)
     b = q.op.shape[0]
     if index is not None:
         rows = jnp.where(u_axis == 1, pmeta.n_subjects + u_key, u_key)
@@ -237,14 +235,17 @@ def _serve_local(
     b = q.op.shape[0]
     is_check = q.op == OP_CHECK
     hit = k2forest.check(
-        meta, f, jnp.maximum(q.p - 1, 0), q.s - 1, q.o - 1
+        meta, f, q.p - 1, q.s - 1, q.o - 1
     ) & is_check
     axes = jnp.where(q.op == OP_COL, 1, 0).astype(jnp.int32)
-    key = jnp.maximum(jnp.where(q.op == OP_COL, q.o, q.s) - 1, 0)
-    r = k2forest.scan_batch_mixed(
-        meta, f, jnp.maximum(q.p - 1, 0), key, axes, cap, backend
-    )
+    key = jnp.where(q.op == OP_COL, q.o, q.s) - 1
     scan_lane = (q.op == OP_ROW) | (q.op == OP_COL)
+    # lanes that are not scans park on pred -1: the traversal skips them,
+    # as it skips a predicate or key outside the store (k2forest.live_lanes)
+    r = k2forest.scan_batch_mixed(
+        meta, f, jnp.where(scan_lane, q.p - 1, -1), key, axes,
+        cap, backend,
+    )
     valid = r.valid & scan_lane[:, None]
     ids = jnp.where(valid, r.ids + 1, 0)
     count = jnp.where(scan_lane, r.count, 0)
@@ -264,12 +265,13 @@ def _serve_local(
     )
     preds_f = jnp.where(cvalid, cpreds, 0).reshape(b * u_width)
     keys_f = jnp.repeat(u_key, u_width)
+    pair_valid = cvalid & is_u_pair[:, None]
 
     # pair lanes: one pruned mixed scan replaces the P-way broadcast sweep
     ru = k2forest.scan_batch_mixed(
-        meta, f, preds_f, keys_f, jnp.repeat(u_axis, u_width), cap, backend
+        meta, f, jnp.where(pair_valid.reshape(-1), preds_f, -1), keys_f,
+        jnp.repeat(u_axis, u_width), cap, backend,
     )
-    pair_valid = cvalid & is_u_pair[:, None]
     u_valid = ru.valid.reshape(b, u_width, cap) & pair_valid[:, :, None]
     u_ids = jnp.where(u_valid, ru.ids.reshape(b, u_width, cap) + 1, 0)
     u_count = jnp.where(pair_valid, ru.count.reshape(b, u_width), 0)
@@ -289,8 +291,8 @@ def _serve_local(
     # the contract.
     hitm = k2forest.check(
         meta, f, preds_f,
-        jnp.repeat(jnp.maximum(q.s - 1, 0), u_width),
-        jnp.repeat(jnp.maximum(q.o - 1, 0), u_width),
+        jnp.repeat(q.s - 1, u_width),
+        jnp.repeat(q.o - 1, u_width),
     ).reshape(b, u_width) & cvalid & is_u_check[:, None]
     valid5, count5, ovf5, (ids5,) = jax.vmap(
         lambda v, a: _compact(v, cap, a)
@@ -346,7 +348,28 @@ def make_serve_step(
 
 
 def shard_forest(f: K2Forest, mesh: Mesh, axis: str = "model") -> K2Forest:
-    """Place the arena with the predicate dimension sharded over ``axis``."""
+    """Place the arena with the predicate dimension sharded over ``axis``.
+
+    ``f.n_preds`` must divide the axis size (:func:`pad_preds`).  Each
+    shard's block of ``P / mp`` trees is laid out in whole 8-row tiles of
+    its own, so the rows of the result are shard blocks, not global
+    predicate ids: it is meant for the sharded serve programs only.
+    """
+    mp = int(mesh.shape[axis])
+    p, rows = f.n_preds, f.t_words.shape[0]
+    if p % mp:
+        raise ValueError(f"{p} predicates do not divide {mp} shards; pad_preds")
+    p_loc = p // mp
+    r_loc = bitvec.round_up(p_loc, bitvec.TILE_ROWS)
+    if r_loc * mp != rows or p_loc != r_loc:
+
+        def blocks(a):
+            a = a[:p].reshape(mp, p_loc, a.shape[1])
+            return jnp.pad(a, ((0, 0), (0, r_loc - p_loc), (0, 0))).reshape(
+                mp * r_loc, -1)
+
+        f = f._replace(**{k: blocks(getattr(f, k)) for k in K2Forest._fields
+                          if k != "nnz"})
     sh = NamedSharding(mesh, P(axis))
     return K2Forest(*(jax.device_put(a, sh) for a in f))
 
@@ -362,17 +385,16 @@ def pad_preds(f: K2Forest, multiple: int) -> K2Forest:
     """Pad the predicate axis so it divides the model-axis size.
 
     Padded trees are all-zeros (valid empty k²-trees): queries routed to them
-    return no results, so padding is semantically inert.
+    return no results, so padding is semantically inert.  The arena rows
+    stay whole 8-row tiles.
     """
     Pn = f.n_preds
     pad = (-Pn) % multiple
     if pad == 0:
         return f
-    out = []
-    for a in f:
-        cfg = [(0, pad)] + [(0, 0)] * (a.ndim - 1)
-        out.append(jnp.pad(a, cfg))
-    return K2Forest(*out)
+    rows = bitvec.round_up(Pn + pad, bitvec.TILE_ROWS)
+    out = [jnp.pad(a, ((0, rows - a.shape[0]), (0, 0))) for a in f[:-1]]
+    return K2Forest(*out, nnz=jnp.pad(f.nnz, (0, pad)))
 
 
 def make_sharded_serve_step(
@@ -411,7 +433,7 @@ def make_sharded_serve_step(
     )
 
     def _local(f_loc: K2Forest, q: ServeBatch, index=None) -> ServeResult:
-        p_loc = f_loc.t_words.shape[0]  # local predicate count
+        p_loc = f_loc.n_preds  # local predicate count
         b = q.op.shape[0]
         shard = jax.lax.axis_index(model_axis)
         g = q.p - 1  # 0-based global predicate
@@ -459,11 +481,11 @@ def make_sharded_serve_step(
         preds_f = jnp.where(mine_u, cpreds % p_loc, 0).reshape(b * u_width)
         keys_f = jnp.repeat(u_key, u_width)
 
-        ru = k2forest.scan_batch_mixed(
-            meta, f_loc, preds_f, keys_f, jnp.repeat(u_axis, u_width), cap,
-            backend,
-        )
         pair_mine = mine_u & is_u_pair[:, None]
+        ru = k2forest.scan_batch_mixed(
+            meta, f_loc, jnp.where(pair_mine.reshape(-1), preds_f, -1), keys_f,
+            jnp.repeat(u_axis, u_width), cap, backend,
+        )
         uv_loc = ru.valid.reshape(b, u_width, cap) & pair_mine[:, :, None]
         u_ids = jax.lax.psum(
             jnp.where(uv_loc, ru.ids.reshape(b, u_width, cap) + 1, 0),
@@ -471,8 +493,8 @@ def make_sharded_serve_step(
         )
         hitm_loc = k2forest.check(
             meta, f_loc, preds_f,
-            jnp.repeat(jnp.maximum(q.s - 1, 0), u_width),
-            jnp.repeat(jnp.maximum(q.o - 1, 0), u_width),
+            jnp.repeat(q.s - 1, u_width),
+            jnp.repeat(q.o - 1, u_width),
         ).reshape(b, u_width) & mine_u & is_u_check[:, None]
         # one packed [B, u_width] reduce: check hits (bit 0), per-candidate
         # counts (needed because a count can legitimately be 0 with no ids)
@@ -509,13 +531,13 @@ def make_sharded_serve_step(
 
     if u_width > 0:
         ispec = PredIndex(*(P() for _ in PredIndex._fields))  # replicated
-        fn = shard_map(
+        fn = jax.shard_map(
             _local, mesh=mesh, in_specs=(fspec, qspec, ispec),
             out_specs=out_spec,
             check_vma=False,  # pallas_call has no replication rule
         )
     else:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda f_loc, q: _local(f_loc, q), mesh=mesh,
             in_specs=(fspec, qspec), out_specs=out_spec,
             check_vma=False,  # pallas_call has no replication rule (scan kernel)
@@ -543,7 +565,7 @@ def make_sharded_unbounded_scan(
     fspec = forest_pspecs(model_axis)
 
     def _local(f_loc: K2Forest, keys: jax.Array, axes: jax.Array):
-        p_loc = f_loc.t_words.shape[0]
+        p_loc = f_loc.n_preds
         b = keys.shape[0]
         # the all-preds sweep as one batched mixed scan with broadcast keys
         preds_f = jnp.tile(jnp.arange(p_loc, dtype=jnp.int32), b)
@@ -560,7 +582,7 @@ def make_sharded_unbounded_scan(
         count = jax.lax.all_gather(count, model_axis, axis=1, tiled=True)
         return ids, valid, count
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _local, mesh=mesh, in_specs=(fspec, qP, qP), out_specs=(qP, qP, qP),
         check_vma=False,  # all_gather(tiled) replication defeats VMA inference
     )

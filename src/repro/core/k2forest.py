@@ -10,10 +10,16 @@ pack them into padded 2-D word arenas ``(P, W)`` so that
 
 All trees share one ``K2Meta`` (same matrix side = dictionary extent, padded
 to the hybrid-k power — exactly the paper's square-matrix construction).
+
+Every 2-D arena is built in whole (8, 128) tiles (``bitvec.TILE_ROWS`` /
+``TILE_COLS``): the predicate rows pad to a multiple of 8 with empty trees,
+the word columns with zero words (``t_rank`` rank-extended).  ``nnz`` keeps
+the logical predicate count.
 """
 
 from __future__ import annotations
 
+import functools
 import types
 from typing import NamedTuple, Sequence
 
@@ -26,16 +32,16 @@ from repro.core.k2tree import K2Meta, PairResult, QueryResult, _compact
 
 
 class K2Forest(NamedTuple):
-    t_words: jax.Array  # uint32[P, Wt]
-    t_rank: jax.Array  # int32[P, Wt]
-    l_words: jax.Array  # uint32[P, Wl]
-    ones_before: jax.Array  # int32[P, max(H-1,1)]
-    level_start: jax.Array  # int32[P, H]
+    t_words: jax.Array  # uint32[R, Wt]   R = P rounded up to 8 rows,
+    t_rank: jax.Array  # int32[R, Wt]     Wt, Wl and the table widths
+    l_words: jax.Array  # uint32[R, Wl]   rounded up to 128 columns
+    ones_before: jax.Array  # int32[R, >= H-1]
+    level_start: jax.Array  # int32[R, >= H]
     nnz: jax.Array  # int32[P]
 
     @property
     def n_preds(self) -> int:
-        return self.t_words.shape[0]
+        return self.nnz.shape[0]
 
 
 class ForestStats(NamedTuple):
@@ -54,14 +60,16 @@ def build_forest(
     hosts = [k2tree.build_host(r, c, meta) for (r, c) in coords]
     P = len(hosts)
     H = meta.n_levels
-    wt = max(1, max((h.t_bits.shape[0] + 31) // 32 for h in hosts))
-    wl = max(1, max((h.l_bits.shape[0] + 31) // 32 for h in hosts))
+    tile = functools.partial(bitvec.round_up, m=bitvec.TILE_COLS)
+    R = bitvec.round_up(max(P, 1), bitvec.TILE_ROWS)
+    wt = tile(max([1] + [(h.t_bits.shape[0] + 31) // 32 for h in hosts]))
+    wl = tile(max([1] + [(h.l_bits.shape[0] + 31) // 32 for h in hosts]))
 
-    t_words = np.zeros((P, wt), np.uint32)
-    t_rank = np.zeros((P, wt), np.int32)
-    l_words = np.zeros((P, wl), np.uint32)
-    ones_before = np.zeros((P, max(H - 1, 1)), np.int32)
-    level_start = np.zeros((P, H), np.int32)
+    t_words = np.zeros((R, wt), np.uint32)
+    t_rank = np.zeros((R, wt), np.int32)
+    l_words = np.zeros((R, wl), np.uint32)
+    ones_before = np.zeros((R, tile(max(H - 1, 1))), np.int32)
+    level_start = np.zeros((R, tile(H)), np.int32)
     nnz = np.zeros((P,), np.int32)
     bits = np.zeros((P,), np.int64)
     for i, h in enumerate(hosts):
@@ -75,7 +83,7 @@ def build_forest(
         lw = bitvec.pack_bits_np(h.l_bits)
         l_words[i, : lw.shape[0]] = lw
         ones_before[i, : h.ones_before.shape[0]] = h.ones_before
-        level_start[i] = h.level_start
+        level_start[i, :H] = h.level_start
         nnz[i] = h.nnz
         bits[i] = h.t_bits.shape[0] + h.l_bits.shape[0]
 
@@ -89,7 +97,7 @@ def build_forest(
     )
     stats = ForestStats(
         total_bits=int(bits.sum()),
-        padded_bits=int(P * (wt + wl) * 32 + t_rank.size * 32),
+        padded_bits=int((t_words.size + t_rank.size + l_words.size) * 32),
         per_pred_bits=bits,
         per_pred_nnz=nnz.copy(),
     )
@@ -101,15 +109,32 @@ def build_forest(
 # ---------------------------------------------------------------------------
 
 
+def live_lanes(meta: K2Meta, f: K2Forest, preds, *keys) -> jax.Array:
+    """Predicate ids -> -1, the dead-lane id, where the predicate is outside
+    ``[0, n_preds)`` or any of ``keys`` is outside the matrix side.
+
+    Client ids reach the traversals unchecked; a dead lane answers empty
+    and the kernels read nothing for it, so no id can steer a read outside
+    the arena or answer from another predicate's tree or another entity.
+    """
+    preds = jnp.asarray(preds, jnp.int32)
+    live = (preds >= 0) & (preds < f.n_preds)
+    for k in keys:
+        k = jnp.asarray(k, jnp.int32)
+        live = live & (k >= 0) & (k < meta.side)
+    return jnp.where(live, preds, -1)
+
+
 def check(
     meta: K2Meta, f: K2Forest, pred: jax.Array, rows: jax.Array, cols: jax.Array
 ) -> jax.Array:
-    """Batched (S, P, O) over per-lane predicates -> bool[Q]."""
+    """Batched (S, P, O) over per-lane predicates -> bool[Q]; an id outside
+    the store is never a hit."""
     H = meta.n_levels
-    pred = pred.astype(jnp.int32)
+    pred = live_lanes(meta, f, pred, rows, cols)
     rd = k2tree._row_digits(meta, rows.astype(jnp.int32))
     cd = k2tree._row_digits(meta, cols.astype(jnp.int32))
-    alive = jnp.ones(rows.shape, dtype=jnp.bool_)
+    alive = jnp.broadcast_to(pred >= 0, rows.shape)
     pos = (rd[0] * meta.ks[0] + cd[0]).astype(jnp.int32)
     for lvl in range(H):
         last = lvl == H - 1
@@ -211,8 +236,9 @@ def _axis_scan_traced(
     p0 = jnp.where(is_row, fdig[0] * k0 + j0, j0 * k0 + fdig[0])
     pos = jnp.zeros((cap,), jnp.int32).at[:init_n].set(p0)
     base = jnp.zeros((cap,), jnp.int32).at[:init_n].set(j0 * sub0)
-    valid = jnp.zeros((cap,), jnp.bool_).at[:init_n].set(True)
-    overflow = jnp.asarray(k0 > cap)
+    live = pred >= 0  # a dead lane (pred < 0) answers empty
+    valid = jnp.zeros((cap,), jnp.bool_).at[:init_n].set(live)
+    overflow = jnp.asarray(k0 > cap) & live
 
     words0 = f.l_words if H == 1 else f.t_words
     valid = valid & (bitvec.get_bit_2d(words0, pred, pos) == 1)
@@ -248,6 +274,11 @@ def scan_batch_mixed(
 ) -> QueryResult:
     """Batched mixed row/col scans: axes[i]==0 -> row (S,P,?O), 1 -> col.
 
+    A lane whose predicate is outside ``[0, n_preds)`` or whose key is
+    outside the matrix is dead (:func:`live_lanes`): empty, no overflow,
+    and the kernel reads nothing for it (the serve step parks unused lanes
+    at -1).
+
     ``backend`` selects the compute substrate: an ``ExecConfig``
     (``core.query``) carries explicit backend + interpret values (the
     compiled-plan path — zero env reads); a bare "pallas"/"jnp" string or
@@ -258,6 +289,7 @@ def scan_batch_mixed(
     """
     from repro.kernels import ops  # deferred: core must import without pallas
 
+    preds = live_lanes(meta, f, preds, keys)
     be, interp = ops.resolve_exec(backend)
     if be == "pallas":
         ids, valid, count, overflow = ops.k2_scan_forest(
@@ -265,7 +297,7 @@ def scan_batch_mixed(
         )
         return QueryResult(ids=ids, valid=valid, count=count, overflow=overflow)
     return jax.vmap(lambda p, x, a: _axis_scan_traced(meta, f, p, x, a, cap))(
-        jnp.asarray(preds), jnp.asarray(keys), jnp.asarray(axes)
+        preds, jnp.asarray(keys), jnp.asarray(axes)
     )
 
 
@@ -287,7 +319,7 @@ def _range_scan_traced(meta: K2Meta, f: K2Forest, pred: jax.Array, cap: int) -> 
     words0 = f.l_words if H == 1 else f.t_words
     bit0 = bitvec.get_bit_2d(words0, pred, d0)
     valid, _, ovf, (pos, rbase, cbase) = _compact(
-        bit0 == 1, cap, d0, (d0 // k0) * sub0, (d0 % k0) * sub0
+        (bit0 == 1) & (pred >= 0), cap, d0, (d0 // k0) * sub0, (d0 % k0) * sub0
     )
     overflow = ovf
     pos = jnp.where(valid, pos, 0)
@@ -319,7 +351,8 @@ def _range_scan_traced(meta: K2Meta, f: K2Forest, pred: jax.Array, cap: int) -> 
 def range_scan_batch(
     meta: K2Meta, f: K2Forest, preds, cap: int, backend: str | None = None
 ) -> PairResult:
-    """Batched (?S, P, ?O) pair enumeration, one lane per predicate.
+    """Batched (?S, P, ?O) pair enumeration, one lane per predicate; a
+    predicate outside ``[0, n_preds)`` is a dead lane (no pairs).
 
     ``backend`` resolves exactly like ``scan_batch_mixed`` (ExecConfig /
     string / None): "pallas" routes to the batched ``kernels.k2_range``
@@ -328,7 +361,7 @@ def range_scan_batch(
     """
     from repro.kernels import ops  # deferred: core must import without pallas
 
-    preds = jnp.asarray(preds, jnp.int32)
+    preds = live_lanes(meta, f, preds)
     be, interp = ops.resolve_exec(backend)
     if be == "pallas":
         rows, cols, valid, count, overflow = ops.k2_range_forest(
@@ -374,10 +407,10 @@ def scan_rebind_batch(
     """
     from repro.kernels import ops  # deferred: core must import without pallas
 
-    preds1 = jnp.asarray(preds1, jnp.int32)
+    preds1 = live_lanes(meta, f, preds1, keys1)
     keys1 = jnp.asarray(keys1, jnp.int32)
     axes1 = jnp.asarray(axes1, jnp.int32)
-    preds2 = jnp.asarray(preds2, jnp.int32)
+    preds2 = live_lanes(meta, f, preds2)
     axes2 = jnp.asarray(axes2, jnp.int32)
     be, interp = ops.resolve_exec(backend)
     if be == "pallas":

@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import k2forest
+from repro.core import bitvec, k2forest
 from repro.core.bitvec import popcount_np
 from repro.core.k2forest import K2Forest
 from repro.core.k2tree import K2Meta, QueryResult, _compact
@@ -85,6 +85,9 @@ class PredIndex(NamedTuple):
         concatenated per-level chunk byte streams, ``flags`` the per-level
         continuation bitmaps (word aligned per level), ``frank``
         int32 exclusive in-level popcount per flag word.
+
+    Every used array is zero-padded to whole (8, 128) tiles (a multiple of
+    1024 entries, ``bitvec.tile_pad_1d``), the form the kernels read.
     """
 
     offsets: jax.Array  # int32 — CSR row pointers (fixed) | block anchors (dac)
@@ -283,6 +286,11 @@ def _pack_degrees(counts: np.ndarray, offsets: np.ndarray, max_degree: int):
     return anchors, degs, deg_width, rows_per_block
 
 
+def _tiled(a: np.ndarray) -> jax.Array:
+    """A device array in whole (8, 128) tiles, zero-padded past its end."""
+    return jnp.asarray(bitvec.tile_pad_1d(a))
+
+
 def build(
     ids: np.ndarray, *, n_subjects: int, n_objects: int, n_preds: int,
     n_triples: int | None = None,
@@ -360,11 +368,11 @@ def build(
     )
     return BuiltPredIndex(
         device=PredIndex(
-            offsets=jnp.asarray(anchors, jnp.int32),
-            words=jnp.asarray(dac_words),
-            degs=jnp.asarray(degs),
-            flags=jnp.asarray(flags),
-            frank=jnp.asarray(frank),
+            offsets=_tiled(anchors.astype(np.int32)),
+            words=_tiled(dac_words),
+            degs=_tiled(degs),
+            flags=_tiled(flags),
+            frank=_tiled(frank),
         ),
         meta=PredIndexMeta(
             layout="dac", levels=levels, level_byte_start=lbs,
@@ -375,8 +383,8 @@ def build(
         host_offsets=offsets,
         host_preds=preds[:n_entries],
         device_fixed=PredIndex(
-            offsets=jnp.asarray(offsets, jnp.int32),
-            words=jnp.asarray(words_fixed),
+            offsets=_tiled(offsets.astype(np.int32)),
+            words=_tiled(words_fixed),
             degs=placeholder_u,
             flags=placeholder_u,
             frank=placeholder_i,

@@ -76,14 +76,40 @@ class AdmissionError(RuntimeError):
     broker translates per-tenant plan quotas into this."""
 
 
-def default_interpret() -> bool:
-    """The ONE definition of the auto interpret default: Pallas interpret
-    mode everywhere except a real TPU backend.  Deterministic — consulted
-    by ``ExecConfig.resolved()`` and ``kernels.ops.resolve_exec`` alike,
-    never by reading the environment."""
+def _backend() -> str:
     import jax
 
-    return jax.default_backend() != "tpu"
+    return jax.default_backend()
+
+
+def default_interpret() -> bool:
+    """The ONE definition of the auto interpret default: compiled kernels
+    on a TPU backend, the Pallas interpreter on the CPU.  Deterministic —
+    consulted by ``ExecConfig.resolved()`` and ``kernels.ops.resolve_exec``
+    alike, never by reading the environment.  Any other backend is an
+    error: the kernels are written for the TPU, and interpreting them
+    there would hide that the device is not in use."""
+    backend = _backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels need a TPU backend (or the CPU interpreter); "
+        f"JAX runs on {backend!r} — use backend='jnp' there"
+    )
+
+
+def check_interpret(interpret: bool, where: str) -> bool:
+    """Refuse Pallas interpret mode on a TPU backend: there the kernels run
+    compiled, and an interpreter would quietly keep them off the chip."""
+    if interpret and _backend() == "tpu":
+        raise ValueError(
+            f"{where}: Pallas interpret mode requested on a TPU backend; "
+            f"the kernels run compiled there (unset REPRO_PALLAS_INTERPRET "
+            f"or set it to 0, and pass interpret=None or False)"
+        )
+    return interpret
 
 
 def is_var(t: Term) -> bool:
@@ -113,9 +139,9 @@ class ExecConfig:
         Traversal substrate: "pallas" (batched TPU kernels) or "jnp"
         (vmapped reference traversal).
     ``interpret``
-        Pallas interpret mode.  ``None`` = auto (interpret everywhere but
-        a real TPU backend) — resolved once at compile time, never from
-        the environment.
+        Pallas interpret mode.  ``None`` = auto (compiled on a TPU, the
+        interpreter on the CPU) — resolved once at compile time, never
+        from the environment.  ``True`` on a TPU backend is an error.
     ``cap`` / ``cap_y``
         Result capacities: ``cap`` for scan/side-list/X lanes, ``cap_y``
         for the re-bind (Y) lanes of join categories D–F.
@@ -197,13 +223,12 @@ class ExecConfig:
             overrides["backend"] = os.environ.get("REPRO_SCAN_BACKEND", "pallas")
         if "interpret" not in overrides:
             # tri-state: unset -> auto (default_interpret), "0" -> force
-            # compiled, anything else -> force interpret.  The pre-fix
-            # expression (`env != "0" and default_interpret()`) collapsed
-            # an explicit "1" into the auto default, silently ignoring it
-            # on TPU backends where the default is False.
+            # compiled, anything else -> force interpret — an error on a
+            # TPU backend, never a quiet fallback
             raw = os.environ.get("REPRO_PALLAS_INTERPRET")
-            overrides["interpret"] = (
-                default_interpret() if raw is None else raw != "0"
+            overrides["interpret"] = check_interpret(
+                default_interpret() if raw is None else raw != "0",
+                "ExecConfig.from_env (REPRO_PALLAS_INTERPRET)",
             )
         if "pred_index_layout" not in overrides:
             overrides["pred_index_layout"] = os.environ.get(
@@ -215,8 +240,10 @@ class ExecConfig:
         """Fill ``interpret=None`` with :func:`default_interpret`.
 
         Deterministic — depends on the jax backend, never the environment.
+        ``interpret=True`` on a TPU backend is an error.
         """
         if self.interpret is not None:
+            check_interpret(self.interpret, "ExecConfig.resolved")
             return self
         return dataclasses.replace(self, interpret=default_interpret())
 

@@ -6,7 +6,9 @@ Each lane carries a frontier of up to ``cap`` nodes as ``(pos, rbase,
 cbase)`` — tree bit position plus the node's row/column submatrix origin —
 and per level expands by the full radix ``k²_{l+1}`` (vs the scan kernel's
 ``k`` free-axis children), so results come out in Morton (level-order)
-sequence: the order the paper's DFS would emit.
+sequence: the order the paper's DFS would emit.  Like ``k2_scan`` it runs
+on the scalar core over the HBM-resident arena (``kernels/tiles.py``), with
+the frontier in SMEM.
 
 Level 0 materializes ALL ``k0²`` root children, tests their bits, and only
 then compacts into the ``cap`` frontier — overflow latches only when more
@@ -30,100 +32,61 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.k2tree import K2Meta
+from repro.kernels import tiles
+from repro.kernels.k2_scan import (
+    append, arena, arena_scratch, bit_at, descend,
+    geo_init, geo_scratch, lane_pad, run_lane,
+)
+from repro.kernels.tiles import Record
 
-from repro.kernels.k2_scan import _bit_at, _compact_rows, _rank_at
-
-
-def _pad_cols(width: int, cap: int, valid, *arrays):
-    """Right-pad candidate columns with dead lanes so ``_compact_rows`` can
-    always gather ``cap`` survivors (level-0 radix may be below cap)."""
-    if width >= cap:
-        return valid, arrays
-    pad = [(0, 0), (0, cap - width)]
-    return (
-        jnp.pad(valid, pad),
-        tuple(jnp.pad(a, pad) for a in arrays),
-    )
+_I32 = jnp.int32
 
 
-def _traverse_range(meta: K2Meta, cap: int, preds,
-                    t_words, t_rank, l_words, ones_before, level_start):
-    """Level-synchronous full-matrix enumeration over (N,) predicate lanes.
+def range_traverse(meta: K2Meta, cap: int, a, fpos, frb, fcb, geo, pred):
+    """One predicate's full-matrix enumeration.  Returns ``(n, overflow)``;
+    the pairs are ``(frb, fcb)[(H - 1) % 2, :n]``."""
+    H, k0, r0, sub0 = meta.n_levels, meta.ks[0], meta.radices[0], meta.subsides[0]
+    t0, w0 = (a.lbit, a.wl) if H == 1 else (a.tbit, a.wt)
 
-    Returns ``(rows, cols, valid, count, overflow)`` with shapes
-    ``(N, cap) ×3, (N,) ×2``.
-    """
-    H = meta.n_levels
-    ks = meta.ks
-    radices = meta.radices
-    subsides = meta.subsides
-    bq = preds.shape[0]
+    # level 0: every root child, bit-tested before the frontier is capped
+    def root(d, c):
+        m, o = c
 
-    # level 0: every root child, bit-tested BEFORE the frontier is capped
-    k0, r0, sub0 = ks[0], radices[0], subsides[0]
-    d0 = jnp.arange(r0, dtype=jnp.int32)[None, :]
-    pos0 = jnp.broadcast_to(d0, (bq, r0)).astype(jnp.int32)
-    rb0 = jnp.broadcast_to((d0 // k0) * sub0, (bq, r0)).astype(jnp.int32)
-    cb0 = jnp.broadcast_to((d0 % k0) * sub0, (bq, r0)).astype(jnp.int32)
-    words0 = l_words if H == 1 else t_words
-    bit0 = _bit_at(words0, jnp.broadcast_to(preds[:, None], (bq, r0)), pos0)
-    valid0, (pos0, rb0, cb0) = _pad_cols(r0, cap, bit0 == 1, pos0, rb0, cb0)
-    valid, _, ovf, (pos, rbase, cbase) = _compact_rows(
-        valid0, cap, pos0, rb0, cb0
-    )
-    overflow = ovf
-    pos = jnp.where(valid, pos, 0)
+        def w(m):
+            fpos[0, m] = d
+            frb[0, m] = (d // k0) * sub0
+            fcb[0, m] = (d % k0) * sub0
 
-    p2 = jnp.broadcast_to(preds[:, None], (bq, cap))
-    for lvl in range(H - 1):
-        last_child = lvl + 1 == H - 1
-        k = ks[lvl + 1]
-        r = radices[lvl + 1]
-        sub = subsides[lvl + 1]
-        j = _rank_at(t_words, t_rank, p2, pos) - ones_before[preds, lvl][:, None]
-        child_base0 = level_start[preds, lvl + 1][:, None] + j * r
-        d = jnp.arange(r, dtype=jnp.int32)[None, None, :]
-        cpos = child_base0[:, :, None] + d
-        crb = rbase[:, :, None] + (d // k) * sub
-        ccb = cbase[:, :, None] + (d % k) * sub
-        wordsc = l_words if last_child else t_words
-        cpos_safe = jnp.where(valid[:, :, None], cpos, 0).reshape(bq, cap * r)
-        cbit = _bit_at(wordsc, jnp.broadcast_to(preds[:, None], (bq, cap * r)),
-                       cpos_safe)
-        cvalid = valid[:, :, None].repeat(r, axis=2).reshape(bq, cap * r) & (cbit == 1)
-        valid, _, ovf, (pos, rbase, cbase) = _compact_rows(
-            cvalid, cap, cpos_safe,
-            crb.reshape(bq, cap * r), ccb.reshape(bq, cap * r),
-        )
-        overflow = overflow | ovf
-        pos = jnp.where(valid, pos, 0)
+        return append(cap, bit_at(t0, w0, pred, d), m, o, w)
 
-    valid, count, ovf, (rows, cols) = _compact_rows(valid, cap, rbase, cbase)
-    return rows, cols, valid, count, overflow | ovf
+    n, ovf = jax.lax.fori_loop(0, r0, root, (_I32(0), _I32(0)))
 
+    def children(lvl, i, cb0, bits, m, o):
+        k, r, sub = geo[0, lvl + 1], geo[1, lvl + 1], geo[2, lvl + 1]
+        src, dst = lvl & 1, (lvl + 1) & 1
+        rb, cb = frb[src, i], fcb[src, i]
 
-def _make_range_kernel(meta: K2Meta, cap: int):
-    def kernel(preds_ref, t_words_ref, t_rank_ref, l_words_ref,
-               ones_before_ref, level_start_ref,
-               rows_ref, cols_ref, valid_ref, count_ref, ovf_ref):
-        rows, cols, valid, count, ovf = _traverse_range(
-            meta, cap, preds_ref[...],
-            t_words_ref[...], t_rank_ref[...], l_words_ref[...],
-            ones_before_ref[...], level_start_ref[...],
-        )
-        rows_ref[...] = rows
-        cols_ref[...] = cols
-        valid_ref[...] = valid
-        count_ref[...] = count
-        ovf_ref[...] = ovf
+        def child(d, c):
+            m, o = c
 
-    return kernel
+            def w(m):
+                fpos[dst, m] = cb0 + d
+                frb[dst, m] = rb + jax.lax.div(d, k) * sub
+                fcb[dst, m] = cb + jax.lax.rem(d, k) * sub
+
+            return append(cap, bits(cb0 + d), m, o, w)
+
+        return jax.lax.fori_loop(0, r, child, (m, o))
+
+    n, lo = descend(meta, a, fpos, geo, pred, n, children)
+    return n, ovf | lo
 
 
 @functools.partial(
-    jax.jit, static_argnames=("meta", "cap", "block_q", "interpret")
+    jax.jit, static_argnames=("meta", "cap", "interpret")
 )
 def k2_range(
     meta: K2Meta,
@@ -135,36 +98,67 @@ def k2_range(
     level_start: jax.Array,
     *,
     cap: int,
-    block_q: int = 8,
     interpret: bool = False,
 ):
     """Batched full-matrix pair enumeration over a K2Forest arena.
 
     Returns ``(rows, cols, valid, count, overflow)`` with shapes
-    ``(Q, cap) ×3, (Q,) ×2``.  Q must divide by block_q.
+    ``(Q, cap) ×3, (Q,) ×2``.
     """
     (q,) = preds.shape
-    assert q % block_q == 0, (q, block_q)
-    grid = (q // block_q,)
-    whole = lambda a: pl.BlockSpec(a.shape, lambda i: tuple(0 for _ in a.shape))
-    qvec = pl.BlockSpec((block_q,), lambda i: (i,))
-    qmat = pl.BlockSpec((block_q, cap), lambda i: (i, 0))
-    return pl.pallas_call(
-        _make_range_kernel(meta, cap),
-        grid=grid,
-        in_specs=[
-            qvec,
-            whole(t_words), whole(t_rank), whole(l_words),
-            whole(ones_before), whole(level_start),
+    bq, qp = tiles.lane_blocks(q)
+    arrs = tiles.tiled("k2_range", t_words, t_rank, l_words, ones_before,
+                       level_start)
+    rows = tiles.rec_rows(2 * cap + 2)
+    par = (meta.n_levels - 1) % 2
+
+    def kernel(preds_ref, tw, tr, lw, ob, ls, out_ref, *scratch):
+        *ascr, fpos, frb, fcb, geo, rbuf, hw, osem, st = scratch
+        a = arena((tw, tr, lw, ob, ls), ascr)
+        geo_init(meta, geo)
+        rec = Record(rbuf, hw, osem.at[0])
+        rec.clear()
+        blk = pl.program_id(0)
+
+        def lane(i, c):
+            qi = blk * bq + i
+
+            @pl.when(qi < q)
+            def _():
+                pred = preds_ref[i]
+                run_lane(st, a, pred, lambda: range_traverse(
+                    meta, cap, a, fpos, frb, fcb, geo, pred,
+                ))
+                n = st[0]
+                rec.fill(0, n, lambda j: frb[par, j], mark=0)
+                rec.fill(cap, n, lambda j: fcb[par, j], mark=1)
+                rec.put(2 * cap, n)
+                rec.put(2 * cap + 1, st[1])
+                rec.flush(out_ref, qi)
+
+            return c
+
+        jax.lax.fori_loop(0, bq, lane, 0)
+
+    (p,) = lane_pad(qp, preds)
+    out = pl.pallas_call(
+        kernel,
+        grid=(qp // bq,),
+        in_specs=[pl.BlockSpec((bq,), lambda i: (i,), memory_space=pltpu.SMEM)]
+        + [tiles.ANY] * 5,
+        out_specs=tiles.ANY,
+        out_shape=jax.ShapeDtypeStruct((qp, rows, tiles.TC), _I32),
+        scratch_shapes=[
+            *arena_scratch(),
+            *(pltpu.SMEM((2, cap), _I32) for _ in range(3)), geo_scratch(),
+            pltpu.SMEM((rows, tiles.TC), _I32), pltpu.SMEM((2,), _I32),
+            pltpu.SemaphoreType.DMA((1,)), pltpu.SMEM((2,), _I32),
         ],
-        out_specs=(qmat, qmat, qmat, qvec, qvec),
-        out_shape=(
-            jax.ShapeDtypeStruct((q, cap), jnp.int32),
-            jax.ShapeDtypeStruct((q, cap), jnp.int32),
-            jax.ShapeDtypeStruct((q, cap), jnp.bool_),
-            jax.ShapeDtypeStruct((q,), jnp.int32),
-            jax.ShapeDtypeStruct((q,), jnp.bool_),
-        ),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(preds.astype(jnp.int32),
-      t_words, t_rank, l_words, ones_before, level_start)
+    )(p, *arrs)
+    flat = out.reshape(qp, -1)[:q]
+    count = flat[:, 2 * cap]
+    valid = jnp.arange(cap, dtype=_I32)[None, :] < count[:, None]
+    return (flat[:, :cap], flat[:, cap: 2 * cap], valid, count,
+            flat[:, 2 * cap + 1] != 0)

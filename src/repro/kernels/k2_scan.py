@@ -1,170 +1,265 @@
-"""Pallas TPU kernel: batched k²-tree row/col scans (the (S,P,?O)/(?S,P,O) path).
+"""Pallas TPU kernels: batched k²-tree row/col scans, and the fused scan→rebind.
 
-This is the scan counterpart of ``k2_check``: one grid step processes a
-(BQ,)-block of queries against the **forest** arenas (``(P, W)`` padded word
-matrices — vertical partitioning's whole-arena VMEM residency; a
-dbpedia-scale forest is a few MB, within the ~16 MB/core budget).  Each query
-lane carries its own (pred, key, axis): ``axis == 0`` scans a row (direct
+A query lane carries (pred, key, axis): ``axis == 0`` scans a row (direct
 neighbors, (S,P,?O)), ``axis == 1`` a column (reverse neighbors, (?S,P,O)) —
-the mixed-batch contract of ``core/k2forest.scan_batch_mixed``.
+the mixed-batch contract of ``core/k2forest.scan_batch_mixed``.  A lane whose
+``pred`` is not a row of the arena is dead: it answers empty and reads
+nothing (``scan_batch_mixed`` sends every id outside ``[0, n_preds)`` here
+as -1).
 
-The traversal is the level-synchronous frontier BFS from ``core/k2tree``,
-statically unrolled over the (tiny) tree height.  Per level, each of the
-``cap`` frontier lanes does
+The forest arenas stay in HBM.  At the geonames Table-1 scale the padded
+forest is 176 MB (``t_words`` and ``t_rank`` are (24, 868,992) each, in
+whole (8, 128) tiles as the store builds them), far beyond VMEM, so the traversal runs on the scalar core and reads words
+through the SMEM tile cache of ``kernels/tiles.py``.
 
-    word   = words[pred, pos >> 5]          (2-D dynamic gather, minor dim)
-    rank   = t_rank[pred, pos >> 5] + popcount(word & mask)
-    children expand along the free axis     (frontier (cap,) -> (cap·k,))
-    compact valid children to the front     (stable: keeps ID-sorted order)
+The traversal is the level-synchronous frontier BFS of ``core/k2tree``,
+statically unrolled over the tree height.  The frontier lives in two SMEM
+buffers; per level, each frontier node in order does
 
-Compaction is phrased as a **stable argsort of the invalid flag** followed by
-a gather — a fixed-shape, sort-network-friendly formulation (XLA lowers it to
-``lax.sort``; on TPU this is the standard bitonic path) that exactly
-reproduces the scatter-based ``_compact`` of the jnp reference, including
-which candidates survive when the frontier exceeds ``cap`` (the first ``cap``
-in free-axis order) and the zeroing of dead lanes.
+    j     = rank1(T, pos) - ones_before[pred, lvl]
+    child = level_start[pred, lvl + 1] + j * k² + (free-axis offset of ch)
+    keep the children whose bit is set, while fewer than ``cap`` are kept
 
-Outputs per query: ``ids[cap]`` (free-axis coordinates, ascending),
-``valid[cap]``, ``count`` = min(#results, cap), ``overflow`` latched if any
-level's frontier was truncated.  Bit-exact against ``ref.k2_scan_ref`` and
-``k2forest.scan_batch_mixed`` (jnp backend); validated with
-``interpret=True`` against the numpy dense oracle in ``tests/test_k2_scan.py``.
+which is the jnp reference's compaction: the survivors are the first ``cap``
+set children in free-axis order, and ``overflow`` latches when a level holds
+more.  A level stops at its first overflow, since no later child survives.
+
+Outputs per lane: ``ids[cap]`` (free-axis coordinates, ascending, zero past
+``count``), ``valid`` (the prefix ``i < count``), ``count`` and ``overflow``.
+Bit-exact against ``ref.k2_scan_ref`` and ``k2forest.scan_batch_mixed``
+(jnp backend), checked in interpret mode by ``tests/test_k2_scan.py``.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.k2tree import K2Meta
+from repro.kernels import tiles
+from repro.kernels.tiles import Record, Tiles
+
+_U32, _I32 = jnp.uint32, jnp.int32
 
 
-def _bit_at(words2d: jax.Array, pred2d: jax.Array, pos: jax.Array) -> jax.Array:
-    """Gather bit ``pos`` of tree ``pred`` from a (P, W) word arena."""
-    widx = jnp.clip(pos >> 5, 0, words2d.shape[-1] - 1)
-    word = words2d[pred2d, widx]
-    return ((word >> (pos & 31).astype(jnp.uint32)) & jnp.uint32(1)).astype(jnp.int32)
+class Arena(NamedTuple):
+    """The forest's tile slots inside a kernel, and its shape."""
+
+    rank: Tiles  # (t_words, t_rank) at the rank position
+    tbit: Tiles  # t_words at a child position
+    lbit: Tiles  # l_words at a child position
+    tab: Tiles  # (ones_before, level_start) of the lane's predicate
+    wt: int
+    wl: int
+    rows: int  # predicate rows of the arena
 
 
-def _rank_at(
-    words2d: jax.Array, rank2d: jax.Array, pred2d: jax.Array, pos: jax.Array
-) -> jax.Array:
-    widx = jnp.clip(pos >> 5, 0, words2d.shape[-1] - 1)
-    word = words2d[pred2d, widx]
-    base = rank2d[pred2d, widx]
-    mask = (jnp.uint32(1) << (pos & 31).astype(jnp.uint32)) - jnp.uint32(1)
-    return base + jax.lax.population_count(word & mask).astype(jnp.int32)
+def arena_scratch() -> list:
+    return [
+        *tiles.tile_bufs(_U32, _I32, _U32, _U32, _I32, _I32),
+        pltpu.SMEM((4,), _I32),  # tile tags
+        pltpu.SemaphoreType.DMA((6,)),
+    ]
 
 
-def _compact_rows(valid: jax.Array, cap: int, *arrays: jax.Array):
-    """Stable per-row compaction (BQ, N) -> (BQ, cap), valid lanes first.
-
-    Matches ``core.k2tree._compact`` exactly: survivors are the first
-    min(#valid, cap) valid candidates in lane order; dropped/dead slots are
-    zeroed.  Phrased as stable argsort + gather instead of scatter-drop.
-    """
-    order = jnp.argsort(~valid, axis=-1, stable=True)[:, :cap]
-    n = jnp.minimum(valid.sum(axis=-1), cap).astype(jnp.int32)
-    new_valid = jnp.arange(cap, dtype=jnp.int32)[None, :] < n[:, None]
-    outs = tuple(
-        jnp.where(new_valid, jnp.take_along_axis(a, order, axis=-1), 0)
-        for a in arrays
+def arena(refs, scratch) -> Arena:
+    """Bind the five arena refs and :func:`arena_scratch` into tile slots."""
+    tw, tr, lw, ob, ls = refs
+    bw, br, bt, bl, bo, bs, tags, sems = scratch
+    a = Arena(
+        rank=Tiles((tw, tr), (bw, br), tags, 0, sems.at[pl.ds(0, 2)]),
+        tbit=Tiles((tw,), (bt,), tags, 1, sems.at[pl.ds(2, 1)]),
+        lbit=Tiles((lw,), (bl,), tags, 2, sems.at[pl.ds(3, 1)]),
+        tab=Tiles((ob, ls), (bo, bs), tags, 3, sems.at[pl.ds(4, 2)]),
+        wt=tw.shape[1], wl=lw.shape[1], rows=tw.shape[0],
     )
-    overflow = valid.sum(axis=-1) > cap
-    return new_valid, n, overflow, outs
+    for t in (a.rank, a.tbit, a.lbit, a.tab):
+        t.reset()
+    return a
 
 
-def _traverse(meta: K2Meta, cap: int, preds, keys, is_row,
-              t_words, t_rank, l_words, ones_before, level_start):
-    """Level-synchronous frontier BFS over (N,) mixed row/col queries.
+def bit_at(t: Tiles, width: int, pred, pos):
+    widx = jnp.clip(pos >> 5, 0, width - 1)
+    (word,) = t.get(pred, widx)
+    return ((word >> (pos & 31).astype(_U32)) & _U32(1)).astype(_I32)
 
-    The shared kernel body: returns ``(ids, valid, count, overflow)`` with
-    shapes ``(N, cap) / (N, cap) / (N,) / (N,)``.  Used by both the plain
-    scan kernel and the fused scan→rebind kernel (which runs it twice).
+
+def rank_at(a: Arena, pred, pos):
+    widx = jnp.clip(pos >> 5, 0, a.wt - 1)
+    word, base = a.rank.get(pred, widx)
+    mask = (_U32(1) << (pos & 31).astype(_U32)) - _U32(1)
+    return base + tiles.popcount(word & mask)
+
+
+def frontier_loop(n, step):
+    """Run ``step(i, m, ovf) -> (m, ovf)`` over frontier nodes ``i < n``,
+    stopping at the first overflow.  Returns the final ``(m, ovf)``."""
+
+    def cond(c):
+        i, _, ovf = c
+        return (i < n) & (ovf == 0)
+
+    def body(c):
+        i, m, ovf = c
+        m, ovf = step(i, m, ovf)
+        return i + 1, m, ovf
+
+    _, m, ovf = jax.lax.while_loop(cond, body, (_I32(0), _I32(0), _I32(0)))
+    return m, ovf
+
+
+def append(cap: int, bit, m, ovf, writes):
+    """Keep a child whose ``bit`` is set when there is room (``writes(m)``
+    stores it at slot m); latch overflow when there is none."""
+    take = (bit == 1) & (m < cap)
+
+    @pl.when(take)
+    def _():
+        writes(m)
+
+    ovf = ovf | ((bit == 1) & (m >= cap)).astype(_I32)
+    return m + take.astype(_I32), ovf
+
+
+GEO_ROWS = 4  # per-level k, k², subside, and the lane's key digit
+
+
+def geo_scratch():
+    return pltpu.SMEM((GEO_ROWS, tiles.TC), _I32)
+
+
+def geo_init(meta: K2Meta, geo) -> None:
+    """Per-level tree geometry into SMEM, so levels can run in a loop."""
+    for lvl, (k, sub) in enumerate(zip(meta.ks, meta.subsides)):
+        geo[0, lvl] = _I32(k)
+        geo[1, lvl] = _I32(k * k)
+        geo[2, lvl] = _I32(sub)
+
+
+def descend(meta: K2Meta, a: Arena, fpos, geo, pred, n, children):
+    """Levels 1..H-1 of a frontier BFS.
+
+    Level ``lvl + 1`` is built from the ``n`` nodes of ``fpos[lvl % 2]``:
+    node i's children start at ``cb0 = level_start + (rank1(T, pos) -
+    ones_before) * k²`` and ``children(lvl, i, cb0, bits, m, ovf) -> (m,
+    ovf)`` appends the set ones, reading their bits with ``bits(cpos)``.
+    Inner levels run as one loop; the last (its bits are in L) runs after.
+    Returns ``(n, overflow)`` of the last level.
     """
     H = meta.n_levels
-    ks = meta.ks
-    radices = meta.radices
-    subsides = meta.subsides
-    bq = preds.shape[0]
-    p2 = jnp.broadcast_to(preds[:, None], (bq, cap))
 
-    # per-level digit of the bound coordinate (static unroll)
-    fdig = []
-    rem = keys
-    for sub in subsides:
-        fdig.append(rem // sub)
-        rem = rem % sub
+    def expand(lvl, n, tc, wc):
+        ob = a.tab.get(pred, lvl)[0]
+        ls = a.tab.get(pred, lvl + 1)[1]
+        r = geo[1, lvl + 1]
+        src = lvl & 1
 
-    # level-0 frontier: the k0 children of the root along the free axis
-    k0, sub0 = ks[0], subsides[0]
-    init_n = min(k0, cap)
-    lane = jnp.arange(cap, dtype=jnp.int32)
-    on = lane < init_n
-    j0 = jnp.minimum(lane, init_n - 1)[None, :]
-    p0 = jnp.where(is_row[:, None], fdig[0][:, None] * k0 + j0,
-                   j0 * k0 + fdig[0][:, None])
-    pos = jnp.where(on[None, :], p0, 0).astype(jnp.int32)
-    base = jnp.broadcast_to(
-        jnp.where(on[None, :], j0 * sub0, 0), (bq, cap)
-    ).astype(jnp.int32)
-    valid = jnp.broadcast_to(on[None, :], (bq, cap))
-    overflow = jnp.full((bq,), k0 > cap, jnp.bool_)
+        def step(i, m, o):
+            cb0 = ls + (rank_at(a, pred, fpos[src, i]) - ob) * r
+            return children(lvl, i, cb0, lambda c: bit_at(tc, wc, pred, c), m, o)
 
-    words0 = l_words if H == 1 else t_words
-    valid = valid & (_bit_at(words0, p2, pos) == 1)
+        return frontier_loop(n, step)
 
-    for lvl in range(H - 1):
-        last_child = lvl + 1 == H - 1
-        k = ks[lvl + 1]
-        r = radices[lvl + 1]
-        sub = subsides[lvl + 1]
-        j = _rank_at(t_words, t_rank, p2, pos) - ones_before[preds, lvl][:, None]
-        child_base0 = level_start[preds, lvl + 1][:, None] + j * r
-        ch = jnp.arange(k, dtype=jnp.int32)[None, None, :]
-        cpos = child_base0[:, :, None] + jnp.where(
-            is_row[:, None, None],
-            fdig[lvl + 1][:, None, None] * k + ch,
-            ch * k + fdig[lvl + 1][:, None, None],
-        )
-        cbase = base[:, :, None] + ch * sub
-        wordsc = l_words if last_child else t_words
-        cpos_safe = jnp.where(valid[:, :, None], cpos, 0).reshape(bq, cap * k)
-        cbit = _bit_at(wordsc, jnp.broadcast_to(preds[:, None], (bq, cap * k)),
-                       cpos_safe)
-        cvalid = valid[:, :, None].repeat(k, axis=2).reshape(bq, cap * k) & (cbit == 1)
-        valid, _, ovf, (pos, base) = _compact_rows(
-            cvalid, cap, cpos_safe, cbase.reshape(bq, cap * k)
-        )
-        overflow = overflow | ovf
-        pos = jnp.where(valid, pos, 0)
+    def inner(lvl, c):
+        n, ovf = c
+        n, lo = expand(lvl, n, a.tbit, a.wt)
+        return n, ovf | lo
 
-    valid, count, ovf, (ids,) = _compact_rows(valid, cap, base)
-    return ids, valid, count, overflow | ovf
+    n, ovf = jax.lax.fori_loop(0, max(H - 2, 0), inner, (n, _I32(0)))
+    if H >= 2:
+        n, lo = expand(H - 2, n, a.lbit, a.wl)
+        ovf = ovf | lo
+    return n, ovf
 
 
-def _make_scan_kernel(meta: K2Meta, cap: int):
-    def kernel(preds_ref, keys_ref, axes_ref, t_words_ref, t_rank_ref,
-               l_words_ref, ones_before_ref, level_start_ref,
-               ids_ref, valid_ref, count_ref, ovf_ref):
-        ids, valid, count, ovf = _traverse(
-            meta, cap, preds_ref[...], keys_ref[...], axes_ref[...] == 0,
-            t_words_ref[...], t_rank_ref[...], l_words_ref[...],
-            ones_before_ref[...], level_start_ref[...],
-        )
-        ids_ref[...] = ids
-        valid_ref[...] = valid
-        count_ref[...] = count
-        ovf_ref[...] = ovf
+def scan_traverse(meta: K2Meta, cap: int, a: Arena, fpos, fbase, geo, pred,
+                  key, is_row):
+    """One lane's row/col scan.  Returns ``(n, overflow)``; the ids are
+    ``fbase[(H - 1) % 2, :n]``."""
+    H, k0, sub0 = meta.n_levels, meta.ks[0], meta.subsides[0]
+    rem = key
+    for lvl, sub in enumerate(meta.subsides):
+        geo[3, lvl] = tiles.fdiv(rem, sub)
+        rem = tiles.fmod(rem, sub)
 
-    return kernel
+    t0, w0 = (a.lbit, a.wl) if H == 1 else (a.tbit, a.wt)
+    d0 = geo[3, 0]
+
+    def root(j, n):
+        p0 = jnp.where(is_row, d0 * k0 + j, j * k0 + d0)
+        fpos[0, n] = p0  # n <= j < cap; a clear bit leaves it overwritable
+        fbase[0, n] = j * sub0
+        return n + bit_at(t0, w0, pred, p0)
+
+    n = jax.lax.fori_loop(0, min(k0, cap), root, _I32(0))
+
+    def children(lvl, i, cb0, bits, m, o):
+        k, sub, d = geo[0, lvl + 1], geo[2, lvl + 1], geo[3, lvl + 1]
+        src, dst = lvl & 1, (lvl + 1) & 1
+        base = fbase[src, i]
+
+        def child(ch, c):
+            m, o = c
+            cpos = cb0 + jnp.where(is_row, d * k + ch, ch * k + d)
+
+            def w(m):
+                fpos[dst, m] = cpos
+                fbase[dst, m] = base + ch * sub
+
+            return append(cap, bits(cpos), m, o, w)
+
+        return jax.lax.fori_loop(0, k, child, (m, o))
+
+    n, ovf = descend(meta, a, fpos, geo, pred, n, children)
+    return n, ovf | _I32(k0 > cap)
+
+
+def run_lane(st, a: Arena, pred, fn):
+    """``st[0], st[1] = fn()`` for a live lane (pred a row of the arena),
+    else 0, 0."""
+    st[0] = _I32(0)
+    st[1] = _I32(0)
+
+    @pl.when((pred >= 0) & (pred < a.rows))
+    def _():
+        n, ovf = fn()
+        st[0] = n
+        st[1] = ovf
+
+
+def decode(out, q: int, cap: int):
+    """Records -> ``(ids, valid, count, overflow)``: ids in words
+    ``[0, cap)``, count and overflow at ``cap`` and ``cap + 1``."""
+    flat = out.reshape(out.shape[0], -1)[:q]
+    count = flat[:, cap]
+    valid = jnp.arange(cap, dtype=_I32)[None, :] < count[:, None]
+    return flat[:, :cap], valid, count, flat[:, cap + 1] != 0
+
+
+def _qspec(bq: int):
+    return pl.BlockSpec((bq,), lambda i: (i,), memory_space=pltpu.SMEM)
+
+
+def _params():
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
+
+def lane_pad(qp: int, *vecs):
+    """int32 lane vectors padded to ``qp`` lanes with dead (-1) entries."""
+    out = []
+    for v in vecs:
+        v = jnp.asarray(v, _I32)
+        out.append(jnp.pad(v, (0, qp - v.shape[0]), constant_values=-1))
+    return out
 
 
 @functools.partial(
-    jax.jit, static_argnames=("meta", "cap", "block_q", "interpret")
+    jax.jit, static_argnames=("meta", "cap", "interpret")
 )
 def k2_scan(
     meta: K2Meta,
@@ -178,38 +273,66 @@ def k2_scan(
     level_start: jax.Array,
     *,
     cap: int,
-    block_q: int = 256,
     interpret: bool = False,
 ):
     """Batched mixed row/col scans over a K2Forest arena.
 
     Returns ``(ids, valid, count, overflow)`` with shapes
-    ``(Q, cap) / (Q, cap) / (Q,) / (Q,)``.  Q must divide by block_q.
+    ``(Q, cap) / (Q, cap) / (Q,) / (Q,)``.
     """
     (q,) = preds.shape
-    assert q % block_q == 0, (q, block_q)
-    grid = (q // block_q,)
-    whole = lambda a: pl.BlockSpec(a.shape, lambda i: tuple(0 for _ in a.shape))
-    qvec = pl.BlockSpec((block_q,), lambda i: (i,))
-    qmat = pl.BlockSpec((block_q, cap), lambda i: (i, 0))
-    return pl.pallas_call(
-        _make_scan_kernel(meta, cap),
-        grid=grid,
-        in_specs=[
-            qvec, qvec, qvec,
-            whole(t_words), whole(t_rank), whole(l_words),
-            whole(ones_before), whole(level_start),
+    bq, qp = tiles.lane_blocks(q)
+    arrs = tiles.tiled("k2_scan", t_words, t_rank, l_words, ones_before,
+                       level_start)
+    rows = tiles.rec_rows(cap + 2)
+    par = (meta.n_levels - 1) % 2
+
+    def kernel(preds_ref, keys_ref, axes_ref, tw, tr, lw, ob, ls, out_ref,
+               *scratch):
+        *ascr, fpos, fbase, geo, rbuf, hw, osem, st = scratch
+        a = arena((tw, tr, lw, ob, ls), ascr)
+        geo_init(meta, geo)
+        rec = Record(rbuf, hw, osem.at[0])
+        rec.clear()
+        blk = pl.program_id(0)
+
+        def lane(i, c):
+            qi = blk * bq + i
+
+            @pl.when(qi < q)
+            def _():
+                pred = preds_ref[i]
+                run_lane(st, a, pred, lambda: scan_traverse(
+                    meta, cap, a, fpos, fbase, geo, pred, keys_ref[i],
+                    axes_ref[i] == 0,
+                ))
+                rec.fill(0, st[0], lambda j: fbase[par, j])
+                rec.put(cap, st[0])
+                rec.put(cap + 1, st[1])
+                rec.flush(out_ref, qi)
+
+            return c
+
+        jax.lax.fori_loop(0, bq, lane, 0)
+
+    p, k, x = lane_pad(qp, preds, keys, axes)
+    out = pl.pallas_call(
+        kernel,
+        grid=(qp // bq,),
+        in_specs=[_qspec(bq)] * 3 + [tiles.ANY] * 5,
+        out_specs=tiles.ANY,
+        out_shape=jax.ShapeDtypeStruct((qp, rows, tiles.TC), _I32),
+        scratch_shapes=[
+            *arena_scratch(),
+            pltpu.SMEM((2, cap), _I32), pltpu.SMEM((2, cap), _I32),
+            geo_scratch(),
+            pltpu.SMEM((rows, tiles.TC), _I32), pltpu.SMEM((1,), _I32),
+            pltpu.SemaphoreType.DMA((1,)), pltpu.SMEM((2,), _I32),
         ],
-        out_specs=(qmat, qmat, qvec, qvec),
-        out_shape=(
-            jax.ShapeDtypeStruct((q, cap), jnp.int32),
-            jax.ShapeDtypeStruct((q, cap), jnp.bool_),
-            jax.ShapeDtypeStruct((q,), jnp.int32),
-            jax.ShapeDtypeStruct((q,), jnp.bool_),
-        ),
+        compiler_params=_params(),
         interpret=interpret,
-    )(preds.astype(jnp.int32), keys.astype(jnp.int32), axes.astype(jnp.int32),
-      t_words, t_rank, l_words, ones_before, level_start)
+    )(p, k, x, *arrs)
+    return decode(out, q, cap)
 
 
 # ---------------------------------------------------------------------------
@@ -217,54 +340,8 @@ def k2_scan(
 # ---------------------------------------------------------------------------
 
 
-def _make_scan_rebind_kernel(meta: K2Meta, cap_x: int, cap_y: int):
-    def kernel(preds1_ref, keys1_ref, axes1_ref, preds2_ref, axes2_ref,
-               t_words_ref, t_rank_ref, l_words_ref, ones_before_ref,
-               level_start_ref,
-               x_ids_ref, x_valid_ref, x_count_ref, x_ovf_ref,
-               y_ids_ref, y_valid_ref, y_count_ref, y_ovf_ref):
-        t_words = t_words_ref[...]
-        t_rank = t_rank_ref[...]
-        l_words = l_words_ref[...]
-        ones_before = ones_before_ref[...]
-        level_start = level_start_ref[...]
-
-        preds1 = preds1_ref[...]                      # (BQ,)
-        bq = preds1.shape[0]
-        x_ids, x_valid, x_count, x_ovf = _traverse(
-            meta, cap_x, preds1, keys1_ref[...], axes1_ref[...] == 0,
-            t_words, t_rank, l_words, ones_before, level_start,
-        )
-
-        # re-bind: every X lane becomes a pattern-2 query.  Dead lanes scan
-        # key 0 (the caller masks y_valid with x_valid) — this matches the
-        # jnp composition's clamp-to-a-safe-id exactly, bit for bit.
-        keys2 = jnp.where(x_valid, x_ids, 0).reshape(bq * cap_x)
-        preds2 = jnp.broadcast_to(
-            preds2_ref[...][:, None], (bq, cap_x)
-        ).reshape(bq * cap_x)
-        is_row2 = jnp.broadcast_to(
-            (axes2_ref[...] == 0)[:, None], (bq, cap_x)
-        ).reshape(bq * cap_x)
-        y_ids, y_valid, y_count, y_ovf = _traverse(
-            meta, cap_y, preds2, keys2, is_row2,
-            t_words, t_rank, l_words, ones_before, level_start,
-        )
-
-        x_ids_ref[...] = x_ids
-        x_valid_ref[...] = x_valid
-        x_count_ref[...] = x_count
-        x_ovf_ref[...] = x_ovf
-        y_ids_ref[...] = y_ids.reshape(bq, cap_x, cap_y)
-        y_valid_ref[...] = y_valid.reshape(bq, cap_x, cap_y)
-        y_count_ref[...] = y_count.reshape(bq, cap_x)
-        y_ovf_ref[...] = y_ovf.reshape(bq, cap_x)
-
-    return kernel
-
-
 @functools.partial(
-    jax.jit, static_argnames=("meta", "cap_x", "cap_y", "block_q", "interpret")
+    jax.jit, static_argnames=("meta", "cap_x", "cap_y", "interpret")
 )
 def k2_scan_rebind(
     meta: K2Meta,
@@ -281,46 +358,102 @@ def k2_scan_rebind(
     *,
     cap_x: int,
     cap_y: int,
-    block_q: int = 1,
     interpret: bool = False,
 ):
     """Fused X-resolution + re-bind: two chained traversals, one kernel.
 
     Per query lane: scan (preds1, keys1, axes1) into a ``cap_x`` side-list of
-    ?X candidates, then — without leaving VMEM — run ``cap_x`` pattern-2
-    scans (preds2, X, axes2) at ``cap_y`` each.  Returns
-    ``(x_ids, x_valid, x_count, x_overflow, y_ids, y_valid, y_count,
-    y_overflow)`` shaped ``(Q,cap_x) ×2, (Q,) ×2, (Q,cap_x,cap_y) ×2,
-    (Q,cap_x) ×2``.  Q must divide by block_q.
+    ?X candidates, then — without leaving the kernel — run ``cap_x``
+    pattern-2 scans (preds2, X, axes2) at ``cap_y`` each; a dead X slot
+    scans key 0, as the jnp composition does.  Returns ``(x_ids, x_valid,
+    x_count, x_overflow, y_ids, y_valid, y_count, y_overflow)`` shaped
+    ``(Q,cap_x) ×2, (Q,) ×2, (Q,cap_x,cap_y) ×2, (Q,cap_x) ×2``.
     """
     (q,) = preds1.shape
-    assert q % block_q == 0, (q, block_q)
-    grid = (q // block_q,)
-    whole = lambda a: pl.BlockSpec(a.shape, lambda i: tuple(0 for _ in a.shape))
-    qvec = pl.BlockSpec((block_q,), lambda i: (i,))
-    qx = pl.BlockSpec((block_q, cap_x), lambda i: (i, 0))
-    qxy = pl.BlockSpec((block_q, cap_x, cap_y), lambda i: (i, 0, 0))
-    return pl.pallas_call(
-        _make_scan_rebind_kernel(meta, cap_x, cap_y),
-        grid=grid,
-        in_specs=[
-            qvec, qvec, qvec, qvec, qvec,
-            whole(t_words), whole(t_rank), whole(l_words),
-            whole(ones_before), whole(level_start),
-        ],
-        out_specs=(qx, qx, qvec, qvec, qxy, qxy, qx, qx),
+    bq, qp = tiles.lane_blocks(q)
+    arrs = tiles.tiled("k2_scan_rebind", t_words, t_rank, l_words,
+                       ones_before, level_start)
+    cmax = max(cap_x, cap_y)
+    rx, ry = tiles.rec_rows(cap_x + 2), tiles.rec_rows(cap_y + 2)
+    par = (meta.n_levels - 1) % 2
+
+    def kernel(p1_ref, k1_ref, a1_ref, p2_ref, a2_ref, tw, tr, lw, ob, ls,
+               xo_ref, yo_ref, *scratch):
+        *ascr, fpos, fbase, geo, xs, xbuf, ybuf, xhw, yhw, osem, st = scratch
+        a = arena((tw, tr, lw, ob, ls), ascr)
+        geo_init(meta, geo)
+        xrec = Record(xbuf, xhw, osem.at[0])
+        yrec = Record(ybuf, yhw, osem.at[1])
+        xrec.clear()
+        yrec.clear()
+        blk = pl.program_id(0)
+
+        def lane(i, c):
+            qi = blk * bq + i
+
+            @pl.when(qi < q)
+            def _():
+                p1, p2 = p1_ref[i], p2_ref[i]
+                row2 = a2_ref[i] == 0
+                run_lane(st, a, p1, lambda: scan_traverse(
+                    meta, cap_x, a, fpos, fbase, geo, p1, k1_ref[i],
+                    a1_ref[i] == 0,
+                ))
+                nx = st[0]
+
+                def keep(j, c):
+                    xs[j] = fbase[par, j]
+                    return c
+
+                jax.lax.fori_loop(0, nx, keep, 0)
+                xrec.fill(0, nx, lambda j: xs[j])
+                xrec.put(cap_x, nx)
+                xrec.put(cap_x + 1, st[1])
+                xrec.flush(xo_ref, qi)
+
+                def rebind(xi, c):
+                    key2 = jnp.where(xi < nx, xs[jnp.minimum(xi, cap_x - 1)], 0)
+                    run_lane(st, a, p2, lambda: scan_traverse(
+                        meta, cap_y, a, fpos, fbase, geo, p2, key2, row2,
+                    ))
+                    yrec.fill(0, st[0], lambda j: fbase[par, j])
+                    yrec.put(cap_y, st[0])
+                    yrec.put(cap_y + 1, st[1])
+                    yrec.flush(yo_ref, qi * cap_x + xi)
+                    return c
+
+                jax.lax.fori_loop(0, cap_x, rebind, 0)
+
+            return c
+
+        jax.lax.fori_loop(0, bq, lane, 0)
+
+    vecs = lane_pad(qp, preds1, keys1, axes1, preds2, axes2)
+    xo, yo = pl.pallas_call(
+        kernel,
+        grid=(qp // bq,),
+        in_specs=[_qspec(bq)] * 5 + [tiles.ANY] * 5,
+        out_specs=(tiles.ANY, tiles.ANY),
         out_shape=(
-            jax.ShapeDtypeStruct((q, cap_x), jnp.int32),
-            jax.ShapeDtypeStruct((q, cap_x), jnp.bool_),
-            jax.ShapeDtypeStruct((q,), jnp.int32),
-            jax.ShapeDtypeStruct((q,), jnp.bool_),
-            jax.ShapeDtypeStruct((q, cap_x, cap_y), jnp.int32),
-            jax.ShapeDtypeStruct((q, cap_x, cap_y), jnp.bool_),
-            jax.ShapeDtypeStruct((q, cap_x), jnp.int32),
-            jax.ShapeDtypeStruct((q, cap_x), jnp.bool_),
+            jax.ShapeDtypeStruct((qp, rx, tiles.TC), _I32),
+            jax.ShapeDtypeStruct((qp * cap_x, ry, tiles.TC), _I32),
         ),
+        scratch_shapes=[
+            *arena_scratch(),
+            pltpu.SMEM((2, cmax), _I32), pltpu.SMEM((2, cmax), _I32),
+            geo_scratch(), pltpu.SMEM((cap_x,), _I32),
+            pltpu.SMEM((rx, tiles.TC), _I32), pltpu.SMEM((ry, tiles.TC), _I32),
+            pltpu.SMEM((1,), _I32), pltpu.SMEM((1,), _I32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), _I32),
+        ],
+        compiler_params=_params(),
         interpret=interpret,
-    )(preds1.astype(jnp.int32), keys1.astype(jnp.int32),
-      axes1.astype(jnp.int32), preds2.astype(jnp.int32),
-      axes2.astype(jnp.int32),
-      t_words, t_rank, l_words, ones_before, level_start)
+    )(*vecs, *arrs)
+    x_ids, x_valid, x_count, x_ovf = decode(xo, q, cap_x)
+    y_ids, y_valid, y_count, y_ovf = decode(yo, q * cap_x, cap_y)
+    return (
+        x_ids, x_valid, x_count, x_ovf,
+        y_ids.reshape(q, cap_x, cap_y), y_valid.reshape(q, cap_x, cap_y),
+        y_count.reshape(q, cap_x), y_ovf.reshape(q, cap_x),
+    )

@@ -18,11 +18,10 @@ once via ``ExecConfig.from_env()``):
 ``REPRO_PALLAS_INTERPRET``
     (re-read on every entry-point call — same semantics as the scan-backend
     flag below; a function already jit-compiled keeps the mode baked in at
-    trace time) "1" (default off-TPU) flips every Pallas kernel into
-    interpret mode — the CPU correctness path used by this container (TPU
-    is the compile target).  On a real TPU backend set
-    ``REPRO_PALLAS_INTERPRET=0`` (the default there: interpret only engages
-    when the backend is not TPU).
+    trace time) Unset: the backend's default — compiled kernels on a TPU,
+    the interpreter on the CPU (the correctness path of CPU tests).  "0"
+    forces compiled kernels; anything else forces the interpreter, which
+    is an error on a TPU backend.
 
 ``REPRO_SCAN_BACKEND``
     (re-read on every resolve — flipping the var mid-session takes effect
@@ -37,8 +36,8 @@ once via ``ExecConfig.from_env()``):
     the index-pruned unbounded-?P lanes):
 
       * ``"pallas"`` (default) — the batched kernels (``kernels/k2_scan.py``
-        / ``kernels/k2_range.py``): whole-arena VMEM residency, one grid
-        step per query block.
+        / ``kernels/k2_range.py``): scalar-core traversals over the
+        HBM-resident arena (``kernels/tiles.py``).
       * ``"jnp"`` — the vmapped pure-jnp level-synchronous traversal
         (the pre-kernel path; also the differential reference).
 
@@ -68,15 +67,16 @@ def pallas_interpret(override: bool | None = None) -> bool:
     """Resolve interpret mode for every Pallas launch.
 
     Re-reads ``REPRO_PALLAS_INTERPRET`` from the environment on every call —
-    the same no-latching contract as ``scan_backend()`` (the original code
-    captured it once into a module constant, so flipping the var after
-    import was silently ignored; tests/test_backend_flag.py).
+    the same no-latching contract as ``scan_backend()``.  Unset means the
+    backend's default (compiled on a TPU, interpreted on the CPU);
+    interpret mode on a TPU backend is an error, never a quiet fallback.
     """
-    if override is not None:
-        return override
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0" and (
-        jax.default_backend() != "tpu"
-    )
+    from repro.core.query import check_interpret, default_interpret
+
+    if override is None:
+        raw = os.environ.get("REPRO_PALLAS_INTERPRET")
+        override = default_interpret() if raw is None else raw != "0"
+    return check_interpret(override, "Pallas kernel launch")
 
 
 def scan_backend(override: str | None = None) -> str:
@@ -108,12 +108,12 @@ def resolve_exec(backend=None) -> tuple[str, bool]:
             raise ValueError(
                 f"unknown scan backend {cfg_backend!r} (want 'pallas' or 'jnp')"
             )
+        from repro.core.query import check_interpret, default_interpret
+
         interp = backend.interpret
         if interp is None:
-            from repro.core.query import default_interpret
-
             interp = default_interpret()
-        return cfg_backend, bool(interp)
+        return cfg_backend, check_interpret(bool(interp), "resolve_exec")
     return scan_backend(backend), pallas_interpret()
 
 
@@ -145,35 +145,23 @@ def k2_scan_forest(
     axes: jax.Array,
     *,
     cap: int,
-    block_q: int = 256,
     interpret: bool | None = None,
 ):
     """Kernel-backed batched mixed row/col scan over a K2Forest.
 
     Drop-in compute for ``core.k2forest.scan_batch_mixed`` (which routes
-    here when the scan backend is "pallas").  Queries are padded up to a
-    ``block_q`` multiple; padded lanes traverse tree 0 at key 0 and are
-    sliced off before returning.  Returns (ids, valid, count, overflow).
+    here when the scan backend is "pallas").  Lanes whose pred is not a
+    row of the arena are dead and answer empty.  Returns (ids, valid, count, overflow).
     ``interpret=None`` defers to the legacy env flag; the compiled-plan
     path always passes an explicit bool.
     """
-    (q,) = jnp.shape(preds)
-    bq = min(block_q, max(1, q))
-    pad = (-q) % bq
-    preds = jnp.asarray(preds, jnp.int32)
-    keys = jnp.asarray(keys, jnp.int32)
-    axes = jnp.asarray(axes, jnp.int32)
-    if pad:
-        preds = jnp.pad(preds, (0, pad))
-        keys = jnp.pad(keys, (0, pad))
-        axes = jnp.pad(axes, (0, pad))
-    ids, valid, count, overflow = _ks.k2_scan(
-        meta, preds, keys, axes,
+    return _ks.k2_scan(
+        meta, jnp.asarray(preds, jnp.int32), jnp.asarray(keys, jnp.int32),
+        jnp.asarray(axes, jnp.int32),
         forest.t_words, forest.t_rank, forest.l_words,
         forest.ones_before, forest.level_start,
-        cap=cap, block_q=bq, interpret=pallas_interpret(interpret),
+        cap=cap, interpret=pallas_interpret(interpret),
     )
-    return ids[:q], valid[:q], count[:q], overflow[:q]
 
 
 def k2_range_forest(
@@ -182,29 +170,20 @@ def k2_range_forest(
     preds: jax.Array,
     *,
     cap: int,
-    block_q: int = 8,
     interpret: bool | None = None,
 ):
     """Kernel-backed batched (?S,P,?O) pair enumeration over a K2Forest.
 
     Drop-in compute for ``core.k2forest.range_scan_batch`` (which routes
-    here when the scan backend is "pallas").  Queries are padded up to a
-    ``block_q`` multiple; padded lanes enumerate tree 0 and are sliced off.
-    Returns (rows, cols, valid, count, overflow).
+    here when the scan backend is "pallas").  Returns (rows, cols, valid,
+    count, overflow).
     """
-    (q,) = jnp.shape(preds)
-    bq = min(block_q, max(1, q))
-    pad = (-q) % bq
-    preds = jnp.asarray(preds, jnp.int32)
-    if pad:
-        preds = jnp.pad(preds, (0, pad))
-    rows, cols, valid, count, overflow = _kr.k2_range(
-        meta, preds,
+    return _kr.k2_range(
+        meta, jnp.asarray(preds, jnp.int32),
         forest.t_words, forest.t_rank, forest.l_words,
         forest.ones_before, forest.level_start,
-        cap=cap, block_q=bq, interpret=pallas_interpret(interpret),
+        cap=cap, interpret=pallas_interpret(interpret),
     )
-    return rows[:q], cols[:q], valid[:q], count[:q], overflow[:q]
 
 
 def k2_scan_rebind_forest(
@@ -218,31 +197,22 @@ def k2_scan_rebind_forest(
     *,
     cap_x: int,
     cap_y: int,
-    block_q: int = 1,
     interpret: bool | None = None,
 ):
     """Kernel-backed fused X-scan + re-bind (join categories D–F).
 
     Drop-in compute for ``core.k2forest.scan_rebind_batch`` (which routes
-    here when the scan backend is "pallas").  The default ``block_q=1``
-    bounds the rebind frontier VMEM at cap_x·cap_y·k lanes per grid step.
-    Returns the kernel's 8-tuple (x_ids, x_valid, x_count, x_overflow,
-    y_ids, y_valid, y_count, y_overflow).
+    here when the scan backend is "pallas").  Returns the kernel's 8-tuple
+    (x_ids, x_valid, x_count, x_overflow, y_ids, y_valid, y_count,
+    y_overflow).
     """
-    (q,) = jnp.shape(preds1)
-    bq = min(block_q, max(1, q))
-    pad = (-q) % bq
     arrs = [jnp.asarray(a, jnp.int32) for a in (preds1, keys1, axes1, preds2, axes2)]
-    if pad:
-        arrs = [jnp.pad(a, (0, pad)) for a in arrs]
-    out = _ks.k2_scan_rebind(
+    return _ks.k2_scan_rebind(
         meta, *arrs,
         forest.t_words, forest.t_rank, forest.l_words,
         forest.ones_before, forest.level_start,
-        cap_x=cap_x, cap_y=cap_y, block_q=bq,
-        interpret=pallas_interpret(interpret),
+        cap_x=cap_x, cap_y=cap_y, interpret=pallas_interpret(interpret),
     )
-    return tuple(a[:q] for a in out)
 
 
 def pred_gather_index(
@@ -251,7 +221,6 @@ def pred_gather_index(
     rows: jax.Array,
     *,
     cap: int,
-    block_q: int = 256,
     interpret: bool | None = None,
 ):
     """Kernel-backed candidate-predicate gather over a PredIndex.
@@ -260,34 +229,26 @@ def pred_gather_index(
     when the scan backend is "pallas").  The decode layout follows
     ``pmeta.layout``: "dac" launches the on-device DAC(b=8) decode kernel,
     "fixed" the byte-packed direct-access kernel.  Rows are clipped to the
-    index range and padded up to a ``block_q`` multiple; padded lanes read
-    row 0 and are sliced off.  Returns (ids, valid, count, overflow).
+    index range.  Returns (ids, valid, count, overflow).
     """
-    (q,) = jnp.shape(rows)
-    bq = min(block_q, max(1, q))
-    pad = (-q) % bq
     rows = jnp.clip(
         jnp.asarray(rows, jnp.int32), 0,
         max(pmeta.n_subjects + pmeta.n_objects - 1, 0),
     )
-    if pad:
-        rows = jnp.pad(rows, (0, pad))
+    interp = pallas_interpret(interpret)
     if getattr(pmeta, "layout", "fixed") == "dac":
-        ids, valid, count, overflow = _pg.pred_gather_dac(
+        return _pg.pred_gather_dac(
             rows, index.offsets, index.words, index.degs, index.flags,
             index.frank, levels=pmeta.levels,
             level_byte_start=pmeta.level_byte_start,
             flag_word_start=pmeta.flag_word_start,
             deg_width=pmeta.deg_width, rows_per_block=pmeta.rows_per_block,
-            cap=cap, block_q=bq, interpret=pallas_interpret(interpret),
+            cap=cap, interpret=interp,
         )
-    else:
-        ids, valid, count, overflow = _pg.pred_gather(
-            rows, index.offsets, index.words,
-            bytes_per_pred=pmeta.bytes_per_pred, cap=cap, block_q=bq,
-            interpret=pallas_interpret(interpret),
-        )
-    return ids[:q], valid[:q], count[:q], overflow[:q]
+    return _pg.pred_gather(
+        rows, index.offsets, index.words,
+        bytes_per_pred=pmeta.bytes_per_pred, cap=cap, interpret=interp,
+    )
 
 
 def sorted_intersect_mask(a_ids: jax.Array, b_ids: jax.Array) -> jax.Array:

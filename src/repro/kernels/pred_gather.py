@@ -6,9 +6,9 @@ that this kernel phrases as a fixed-shape ``(BQ, L)`` launch layout: lane
 ``(q, j)`` holds the j-th predicate of query q's entity row, ready to feed
 the flat ``(query, pred)`` grid of the batched ``k2_scan`` kernel.
 
-Per grid step (one ``(BQ,)`` block of entity rows) with the whole index
-arena (``offsets`` + byte-packed ``words``) VMEM-resident — the index is a
-few bytes per distinct (s,p)/(o,p) pair, far smaller than the forest:
+The index arena stays in HBM and each lane is decoded on the scalar core,
+reading words through the SMEM tile cache of ``kernels/tiles.py`` (the
+Mosaic compiler lowers no dynamic vector gather from an HBM arena):
 
     start  = offsets[row]            deg = offsets[row + 1] - start
     elem   = start + j                              (j = 0 .. L-1)
@@ -34,9 +34,9 @@ on device:
        level's continuation flag is set, the flag's in-level rank
        (``frank[word] + popcount(word & below)``) is the lane's position
        in the next level's byte stream, whose chunk ors in at bits 8·l.
-    3. gaps → ids: an in-kernel log-doubling prefix sum over the lane
-       axis turns the recovered gaps back into ascending 0-based
-       predicate ids (first gap is id+1, so the running sum minus 1).
+    3. gaps → ids: a running sum over the lane's gaps turns them back
+       into ascending 0-based predicate ids (first gap is id+1, so the
+       running sum minus 1).
 
 Bit-exact against ``ref.pred_gather_dac_ref`` (vectorized jnp with
 ``jnp.cumsum`` — an independent implementation) and the fixed-width
@@ -50,37 +50,74 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import tiles
+from repro.kernels.k2_scan import decode, lane_pad
+from repro.kernels.tiles import Record, Tiles
+
+_U32, _I32 = jnp.uint32, jnp.int32
 
 
-def _make_kernel(bytes_per_pred: int, cap: int):
-    mask_val = (1 << (8 * bytes_per_pred)) - 1 if bytes_per_pred < 4 else 0xFFFFFFFF
+def _call(kernel, rows, arrays, dtypes, *, cap, interpret):
+    """Launch a per-row decode kernel over tiled index ``arrays``.
 
-    def kernel(rows_ref, offsets_ref, words_ref,
-               ids_ref, valid_ref, count_ref, ovf_ref):
-        mask = jnp.uint32(mask_val)
-        rows = rows_ref[...]
-        offsets = offsets_ref[...]
-        words = words_ref[...]
-        start = offsets[rows]
-        deg = offsets[rows + 1] - start
-        lane = jnp.arange(cap, dtype=jnp.int32)[None, :]
-        n = jnp.minimum(deg, cap)
-        valid = lane < n[:, None]
-        elem = jnp.where(valid, start[:, None] + lane, 0)
-        bidx = elem * bytes_per_pred
-        word = words[jnp.clip(bidx >> 2, 0, words.shape[0] - 1)]
-        shift = ((bidx & 3) * 8).astype(jnp.uint32)
-        pred = ((word >> shift) & mask).astype(jnp.int32)
-        ids_ref[...] = jnp.where(valid, pred, 0)
-        valid_ref[...] = valid
-        count_ref[...] = n.astype(jnp.int32)
-        ovf_ref[...] = deg > cap
+    ``kernel(rec, tiles_list, row)`` decodes one row into ``rec`` and
+    returns ``(n, overflow)``; ``dtypes`` are the arrays' dtypes (one tile
+    slot each).  Returns ``(ids, valid, count, overflow)``.
+    """
+    (q,) = rows.shape
+    bq, qp = tiles.lane_blocks(q)
+    nrec = tiles.rec_rows(cap + 2)
+    na = len(arrays)
 
-    return kernel
+    def body(rows_ref, *refs):
+        srcs, out_ref, bufs = refs[:na], refs[na], refs[na + 1: 2 * na + 1]
+        tags, sems, rbuf, hw, osem = refs[2 * na + 1:]
+        ts = [Tiles((s,), (b,), tags, i, sems.at[pl.ds(i, 1)])
+              for i, (s, b) in enumerate(zip(srcs, bufs))]
+        for t in ts:
+            t.reset()
+        rec = Record(rbuf, hw, osem.at[0])
+        rec.clear()
+        blk = pl.program_id(0)
+
+        def lane(i, c):
+            qi = blk * bq + i
+
+            @pl.when(qi < q)
+            def _():
+                n, ovf = kernel(rec, ts, rows_ref[i])
+                rec.put(cap, n)
+                rec.put(cap + 1, ovf)
+                rec.flush(out_ref, qi)
+
+            return c
+
+        jax.lax.fori_loop(0, bq, lane, 0)
+
+    (r,) = lane_pad(qp, rows)
+    out = pl.pallas_call(
+        body,
+        grid=(qp // bq,),
+        in_specs=[pl.BlockSpec((bq,), lambda i: (i,), memory_space=pltpu.SMEM)]
+        + [tiles.ANY] * na,
+        out_specs=tiles.ANY,
+        out_shape=jax.ShapeDtypeStruct((qp, nrec, tiles.TC), _I32),
+        scratch_shapes=[
+            *tiles.tile_bufs(*dtypes),
+            pltpu.SMEM((na,), _I32), pltpu.SemaphoreType.DMA((na,)),
+            pltpu.SMEM((nrec, tiles.TC), _I32), pltpu.SMEM((1,), _I32),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(r, *tiles.tiled("pred_gather", *arrays))
+    return decode(out, q, cap)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bytes_per_pred", "cap", "block_q", "interpret")
+    jax.jit, static_argnames=("bytes_per_pred", "cap", "interpret")
 )
 def pred_gather(
     rows: jax.Array,
@@ -89,130 +126,41 @@ def pred_gather(
     *,
     bytes_per_pred: int,
     cap: int,
-    block_q: int = 256,
     interpret: bool = False,
 ):
     """Batched CSR predicate-list gather.
 
     Returns ``(ids, valid, count, overflow)`` with shapes
-    ``(Q, cap) / (Q, cap) / (Q,) / (Q,)``.  Q must divide by block_q;
-    ``rows`` must be pre-clipped to ``[0, len(offsets) - 2]``.
+    ``(Q, cap) / (Q, cap) / (Q,) / (Q,)``.  ``rows`` must be pre-clipped
+    to ``[0, len(offsets) - 2]``.
     """
-    (q,) = rows.shape
-    assert q % block_q == 0, (q, block_q)
-    grid = (q // block_q,)
-    whole = lambda a: pl.BlockSpec(a.shape, lambda i: tuple(0 for _ in a.shape))
-    qvec = pl.BlockSpec((block_q,), lambda i: (i,))
-    qmat = pl.BlockSpec((block_q, cap), lambda i: (i, 0))
-    return pl.pallas_call(
-        _make_kernel(bytes_per_pred, cap),
-        grid=grid,
-        in_specs=[qvec, whole(offsets), whole(words)],
-        out_specs=(qmat, qmat, qvec, qvec),
-        out_shape=(
-            jax.ShapeDtypeStruct((q, cap), jnp.int32),
-            jax.ShapeDtypeStruct((q, cap), jnp.bool_),
-            jax.ShapeDtypeStruct((q,), jnp.int32),
-            jax.ShapeDtypeStruct((q,), jnp.bool_),
-        ),
-        interpret=interpret,
-    )(rows.astype(jnp.int32), offsets, words)
+    mask = (1 << (8 * bytes_per_pred)) - 1 if bytes_per_pred < 4 else 0xFFFFFFFF
+    nw = int(words.shape[0])
 
-
-def _popcount32(w: jax.Array) -> jax.Array:
-    """SWAR popcount of uint32 lanes -> int32 (no population_count dep)."""
-    w = w - ((w >> jnp.uint32(1)) & jnp.uint32(0x55555555))
-    w = (w & jnp.uint32(0x33333333)) + ((w >> jnp.uint32(2)) & jnp.uint32(0x33333333))
-    w = (w + (w >> jnp.uint32(4))) & jnp.uint32(0x0F0F0F0F)
-    return ((w * jnp.uint32(0x01010101)) >> jnp.uint32(24)).astype(jnp.int32)
-
-
-def _make_dac_kernel(
-    cap: int,
-    levels: int,
-    level_byte_start: tuple,
-    flag_word_start: tuple,
-    deg_width: int,
-    rows_per_block: int,
-):
-    per_word = 32 // deg_width
-    dmask_val = (1 << deg_width) - 1 if deg_width < 32 else 0xFFFFFFFF
-
-    def kernel(rows_ref, anchors_ref, words_ref, degs_ref, flags_ref,
-               frank_ref, ids_ref, valid_ref, count_ref, ovf_ref):
-        dmask = jnp.uint32(dmask_val)
-        rows = rows_ref[...]
-        anchors = anchors_ref[...]
-        words = words_ref[...]
-        degs = degs_ref[...]
-        flags = flags_ref[...]
-        frank = frank_ref[...]
-
-        block = rows // rows_per_block
-        within = rows % rows_per_block
-        w0 = block * 4
-        start = anchors[jnp.clip(block, 0, anchors.shape[0] - 1)]
-        # masked SWAR sum of the degrees before `within` inside the block:
-        # static unroll over the block's 4 packed words x per_word lanes
-        for k in range(4):
-            dword = degs[jnp.clip(w0 + k, 0, degs.shape[0] - 1)]
-            for j in range(per_word):
-                idx = k * per_word + j
-                dv = ((dword >> jnp.uint32(j * deg_width)) & dmask).astype(
-                    jnp.int32
-                )
-                start = start + dv * (idx < within).astype(jnp.int32)
-        dword = degs[jnp.clip(w0 + within // per_word, 0, degs.shape[0] - 1)]
-        dsh = ((within % per_word) * deg_width).astype(jnp.uint32)
-        deg = ((dword >> dsh) & dmask).astype(jnp.int32)
-
-        def byte_at(bidx):
-            w = words[jnp.clip(bidx >> 2, 0, words.shape[0] - 1)]
-            return ((w >> ((bidx & 3) * 8).astype(jnp.uint32))
-                    & jnp.uint32(0xFF)).astype(jnp.int32)
-
-        lane = jnp.arange(cap, dtype=jnp.int32)[None, :]
+    def one(rec, ts, row):
+        off, wd = ts
+        start = off.get_flat(row)[0]
+        deg = off.get_flat(row + 1)[0] - start
         n = jnp.minimum(deg, cap)
-        valid = lane < n[:, None]
-        pos = jnp.where(valid, start[:, None] + lane, 0)
-        gap = byte_at(level_byte_start[0] + pos)
-        alive = valid
-        for lvl in range(levels - 1):
-            fidx = jnp.clip(
-                flag_word_start[lvl] + (pos >> 5), 0, flags.shape[0] - 1
-            )
-            fword = flags[fidx]
-            sh = (pos & 31).astype(jnp.uint32)
-            bit = ((fword >> sh) & jnp.uint32(1)) == 1
-            low = fword & ((jnp.uint32(1) << sh) - jnp.uint32(1))
-            rank = frank[fidx] + _popcount32(low)
-            alive = alive & bit
-            pos = jnp.where(alive, rank, 0)
-            chunk = byte_at(level_byte_start[lvl + 1] + pos)
-            gap = gap + jnp.where(alive, chunk << (8 * (lvl + 1)), 0)
 
-        # log-doubling inclusive prefix sum along the lane axis (the
-        # Pallas-side independent implementation vs the ref's jnp.cumsum)
-        acc = jnp.where(valid, gap, 0)
-        d = 1
-        while d < cap:
-            shifted = jnp.where(lane >= d, jnp.roll(acc, d, axis=1), 0)
-            acc = acc + shifted
-            d *= 2
-        preds = acc - 1
-        ids_ref[...] = jnp.where(valid, preds, 0)
-        valid_ref[...] = valid
-        count_ref[...] = n.astype(jnp.int32)
-        ovf_ref[...] = deg > cap
+        def pred(j):
+            bidx = (start + j) * bytes_per_pred
+            (word,) = wd.get_flat(jnp.clip(bidx >> 2, 0, nw - 1))
+            return ((word >> ((bidx & 3) * 8).astype(_U32))
+                    & _U32(mask)).astype(_I32)
 
-    return kernel
+        rec.fill(0, n, pred)
+        return n, (deg > cap).astype(_I32)
+
+    return _call(one, rows, (offsets, words), (_I32, _U32), cap=cap,
+                 interpret=interpret)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "levels", "level_byte_start", "flag_word_start", "deg_width",
-        "rows_per_block", "cap", "block_q", "interpret",
+        "rows_per_block", "cap", "interpret",
     ),
 )
 def pred_gather_dac(
@@ -229,35 +177,68 @@ def pred_gather_dac(
     deg_width: int,
     rows_per_block: int,
     cap: int,
-    block_q: int = 256,
     interpret: bool = False,
 ):
     """Batched DAC(b=8) predicate-list gather + on-device decode.
 
     Returns ``(ids, valid, count, overflow)`` with shapes
-    ``(Q, cap) / (Q, cap) / (Q,) / (Q,)``.  Q must divide by block_q;
-    ``rows`` must be pre-clipped to ``[0, n_rows - 1]``.
+    ``(Q, cap) / (Q, cap) / (Q,) / (Q,)``.  ``rows`` must be pre-clipped
+    to ``[0, n_rows - 1]``.
     """
-    (q,) = rows.shape
-    assert q % block_q == 0, (q, block_q)
-    grid = (q // block_q,)
-    whole = lambda a: pl.BlockSpec(a.shape, lambda i: tuple(0 for _ in a.shape))
-    qvec = pl.BlockSpec((block_q,), lambda i: (i,))
-    qmat = pl.BlockSpec((block_q, cap), lambda i: (i, 0))
-    return pl.pallas_call(
-        _make_dac_kernel(
-            cap, levels, level_byte_start, flag_word_start, deg_width,
-            rows_per_block,
-        ),
-        grid=grid,
-        in_specs=[qvec, whole(anchors), whole(words), whole(degs),
-                  whole(flags), whole(frank)],
-        out_specs=(qmat, qmat, qvec, qvec),
-        out_shape=(
-            jax.ShapeDtypeStruct((q, cap), jnp.int32),
-            jax.ShapeDtypeStruct((q, cap), jnp.bool_),
-            jax.ShapeDtypeStruct((q,), jnp.int32),
-            jax.ShapeDtypeStruct((q,), jnp.bool_),
-        ),
+    per_word = 32 // deg_width
+    dmask = (1 << deg_width) - 1 if deg_width < 32 else 0xFFFFFFFF
+    na, nw, nd, nf = (int(a.shape[0]) for a in (anchors, words, degs, flags))
+
+    def one(rec, ts, row):
+        anc, wd, dg, fl, fr = ts
+        block = tiles.fdiv(row, rows_per_block)
+        within = tiles.fmod(row, rows_per_block)
+        w0 = block * 4
+        start = anc.get_flat(jnp.clip(block, 0, na - 1))[0]
+        # the degrees before `within` in the block: 4 packed words, unrolled
+        for k in range(4):
+            (dword,) = dg.get_flat(jnp.clip(w0 + k, 0, nd - 1))
+            for j in range(per_word):
+                dv = ((dword >> _U32(j * deg_width)) & _U32(dmask)).astype(_I32)
+                start = start + jnp.where(k * per_word + j < within, dv, 0)
+        (dword,) = dg.get_flat(
+            jnp.clip(w0 + tiles.fdiv(within, per_word), 0, nd - 1))
+        dsh = (tiles.fmod(within, per_word) * deg_width).astype(_U32)
+        deg = ((dword >> dsh) & _U32(dmask)).astype(_I32)
+        n = jnp.minimum(deg, cap)
+
+        def byte_at(bidx):
+            (w,) = wd.get_flat(jnp.clip(bidx >> 2, 0, nw - 1))
+            return ((w >> ((bidx & 3) * 8).astype(_U32))
+                    & _U32(0xFF)).astype(_I32)
+
+        def gap_at(j):
+            pos = start + j
+            gap = byte_at(level_byte_start[0] + pos)
+            alive = _I32(1)
+            for lvl in range(levels - 1):
+                fidx = jnp.clip(flag_word_start[lvl] + (pos >> 5), 0, nf - 1)
+                (fword,) = fl.get_flat(fidx)
+                (frk,) = fr.get_flat(fidx)
+                sh = (pos & 31).astype(_U32)
+                alive = alive & ((fword >> sh) & _U32(1)).astype(_I32)
+                low = fword & ((_U32(1) << sh) - _U32(1))
+                pos = jnp.where(alive == 1, frk + tiles.popcount(low), 0)
+                chunk = byte_at(level_byte_start[lvl + 1] + pos)
+                gap = gap + jnp.where(alive == 1, chunk << (8 * (lvl + 1)), 0)
+            return gap
+
+        def lane(j, acc):
+            acc = acc + gap_at(j)
+            rec.put(j, acc - 1)
+            return acc
+
+        jax.lax.fori_loop(0, n, lane, _I32(0))
+        rec.trim(0, n)
+        return n, (deg > cap).astype(_I32)
+
+    return _call(
+        one, rows, (anchors, words, degs, flags, frank),
+        (_I32, _U32, _U32, _U32, _I32), cap=cap,
         interpret=interpret,
-    )(rows.astype(jnp.int32), anchors, words, degs, flags, frank)
+    )

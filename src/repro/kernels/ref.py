@@ -61,9 +61,9 @@ def k2_scan_ref(
 ):
     """Identical semantics to kernels.k2_scan, phrased on raw forest arrays.
 
-    Deliberately uses the scatter-based ``_compact`` (vs the kernel's stable
-    argsort) so kernel-vs-ref agreement checks two independent compaction
-    algorithms.  Returns (ids, valid, count, overflow).
+    Deliberately uses the scatter-based ``_compact`` (vs the kernel's
+    in-order append to an SMEM frontier) so kernel-vs-ref agreement checks
+    two independent compaction algorithms.  Returns (ids, valid, count, overflow).
     """
     from repro.core.k2tree import _compact, _row_digits
 
@@ -79,8 +79,9 @@ def k2_scan_ref(
         p0 = jnp.where(is_row, fdig[0] * k0 + j0, j0 * k0 + fdig[0])
         pos = jnp.zeros((cap,), jnp.int32).at[:init_n].set(p0)
         base = jnp.zeros((cap,), jnp.int32).at[:init_n].set(j0 * sub0)
-        valid = jnp.zeros((cap,), jnp.bool_).at[:init_n].set(True)
-        overflow = jnp.asarray(k0 > cap)
+        live = pred >= 0  # dead lane: empty, no overflow
+        valid = jnp.zeros((cap,), jnp.bool_).at[:init_n].set(live)
+        overflow = jnp.asarray(k0 > cap) & live
 
         words0 = l_words if H == 1 else t_words
         valid = valid & (bitvec.get_bit_2d(words0, pred, pos) == 1)
@@ -127,7 +128,7 @@ def k2_range_ref(
     """Identical semantics to kernels.k2_range, phrased on raw forest arrays.
 
     Like ``k2_scan_ref`` this deliberately uses the scatter-based
-    ``_compact`` (vs the kernel's stable argsort) so agreement checks two
+    ``_compact`` (vs the kernel's in-order append) so agreement checks two
     independent compaction algorithms.  Level 0 bit-tests every root child
     and only then compacts — the fixed overflow semantics.  Returns
     ``(rows, cols, valid, count, overflow)``.
@@ -142,8 +143,9 @@ def k2_range_ref(
         d0 = jnp.arange(r0, dtype=jnp.int32)
         words0 = l_words if H == 1 else t_words
         bit0 = bitvec.get_bit_2d(words0, pred, d0)
-        valid, _, ovf, (pos, rbase, cbase) = _compact(
-            bit0 == 1, cap, d0, (d0 // k0) * sub0, (d0 % k0) * sub0
+        valid, _, ovf, (pos, rbase, cbase) = _compact(  # pred < 0: dead
+            (bit0 == 1) & (pred >= 0), cap, d0, (d0 // k0) * sub0,
+            (d0 % k0) * sub0,
         )
         overflow = ovf
         pos = jnp.where(valid, pos, 0)

@@ -13,17 +13,26 @@ gradient all-reduce over 'pod' is the int8-compression target.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    return jax.make_mesh(shape, axes)
+    """The one mesh constructor: every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+    ``with_sharding_constraint`` and ``shard_map``-inside-``jit`` reject
+    the partition specs this repo writes; all of it assumes ``Auto``.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:
@@ -53,7 +62,29 @@ def serve_mesh_shape(n_devices: int, *, model_max: int = 4) -> tuple[int, int]:
     return shape
 
 
-# TPU v5e hardware constants (per chip) — the roofline denominators
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link
+class Peaks(NamedTuple):
+    """Published per-chip peaks: the roofline denominators."""
+
+    flops_bf16: float  # FLOP/s
+    hbm_bw: float  # B/s
+    ici_bw: float  # B/s per link
+
+
+# Keyed by ``jax.Device.device_kind``.  Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect over 4 links.
+PEAKS = {
+    "TPU v5 lite": Peaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+V5E = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; a kind not in the table is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})"
+        ) from None

@@ -377,14 +377,20 @@ def _engine_forest_specs(cfg, mesh: Mesh):
     bits_per_tree = max(4096, 20 * cfg.n_triples // cfg.n_preds)
     wt = (bits_per_tree * 3 // 4 + 31) // 32
     wl = (bits_per_tree // 4 + 31) // 32
+    from repro.core.bitvec import TILE_COLS, TILE_ROWS, round_up
     from repro.core.k2forest import K2Forest
 
+    # the layout of engine.shard_forest: whole (8, 128) tiles per shard
+    mp = mesh.shape["model"]
+    rows = mp * round_up(P_pad // mp, TILE_ROWS)
+    wt, wl = round_up(wt, TILE_COLS), round_up(wl, TILE_COLS)
+    hc = round_up(H, TILE_COLS)
     return meta, K2Forest(
-        t_words=jax.ShapeDtypeStruct((P_pad, wt), jnp.uint32),
-        t_rank=jax.ShapeDtypeStruct((P_pad, wt), jnp.int32),
-        l_words=jax.ShapeDtypeStruct((P_pad, wl), jnp.uint32),
-        ones_before=jax.ShapeDtypeStruct((P_pad, max(H - 1, 1)), jnp.int32),
-        level_start=jax.ShapeDtypeStruct((P_pad, H), jnp.int32),
+        t_words=jax.ShapeDtypeStruct((rows, wt), jnp.uint32),
+        t_rank=jax.ShapeDtypeStruct((rows, wt), jnp.int32),
+        l_words=jax.ShapeDtypeStruct((rows, wl), jnp.uint32),
+        ones_before=jax.ShapeDtypeStruct((rows, hc), jnp.int32),
+        level_start=jax.ShapeDtypeStruct((rows, hc), jnp.int32),
         nnz=jax.ShapeDtypeStruct((P_pad,), jnp.int32),
     )
 

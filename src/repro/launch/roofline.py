@@ -28,7 +28,10 @@ from __future__ import annotations
 import dataclasses
 import re
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, peaks
+
+# the dry-run models v5e pods: their published peaks are the denominators
+PEAK_FLOPS_BF16, HBM_BW, ICI_BW = peaks(V5E)
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,

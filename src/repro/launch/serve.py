@@ -33,6 +33,7 @@ from repro import obs
 from repro.launch.broker import (
     CoalescePolicy, ServeBroker, TenantPolicy, tail_percentile,
 )
+from repro.launch.cache import use_compile_cache
 
 # mixed-op trace composition: production traffic is mostly point lookups
 # and bounded scans, with a thin unbounded-?P tail (the paper's worst case)
@@ -94,25 +95,28 @@ def make_trace(
     return trace
 
 
-async def _replay(broker: ServeBroker, trace) -> int:
+async def _replay(broker: ServeBroker, trace, answers: dict | None = None) -> int:
     """Replay the trace as one async stream per tenant (per-tenant FIFO),
     counting decoded results.  Rows are ``(tenant, op, s, p, o)`` lanes
     or ``(tenant, SelectQ)`` full-shape queries — ``broker.stream``
-    accepts both item shapes."""
+    accepts both item shapes.  With ``answers``, each decoded result is
+    stored there under its trace index."""
     per_tenant: dict[str, list] = {}
-    for tenant, *rest in trace:
+    for i, (tenant, *rest) in enumerate(trace):
         per_tenant.setdefault(tenant, []).append(
-            rest[0] if len(rest) == 1 else tuple(rest)
+            (i, rest[0] if len(rest) == 1 else tuple(rest))
         )
 
-    async def one(tenant, queries):
+    async def one(tenant, items):
         n = 0
-        async for _ in broker.stream(tenant, queries):
+        async for res in broker.stream(tenant, [q for _, q in items]):
+            if answers is not None:
+                answers[items[n][0]] = res
             n += 1
         return n
 
     counts = await asyncio.gather(
-        *(one(t, qs) for t, qs in per_tenant.items())
+        *(one(t, items) for t, items in per_tenant.items())
     )
     return sum(counts)
 
@@ -138,9 +142,19 @@ def run_bench(
     trace_path: str | None = None,
     metrics_path: str | None = None,
     obs_on: bool = False,
+    ds=None,
+    store=None,
+    trace: list | None = None,
+    answers: dict | None = None,
 ) -> dict:
     """Build a store, serve a skewed multi-tenant trace through the
     broker, and return one machine-readable serving row.
+
+    ``ds`` / ``store`` pass a prebuilt dataset and the store built from
+    it (``n_triples`` / ``n_preds`` are then unused), ``trace`` a
+    prebuilt ``make_trace`` trace; ``answers``, when given, receives
+    every decoded result of the measured replay keyed by trace index, so
+    a caller can check them.
 
     ``trace_path`` / ``metrics_path`` / ``obs_on`` switch the
     observability layer on for the measured window (trace and metrics
@@ -155,19 +169,21 @@ def run_bench(
     from repro.data import rdf
     from repro.launch import mesh as meshlib
 
-    ds = rdf.generate(
-        n_triples,
-        n_subjects=max(64, n_triples // 12),
-        n_preds=n_preds,
-        n_objects=max(64, n_triples // 8),
-        preds_per_subject=min(6, n_preds),
-        seed=seed,
-    )
+    if ds is None:
+        ds = rdf.generate(
+            n_triples,
+            n_subjects=max(64, n_triples // 12),
+            n_preds=n_preds,
+            n_objects=max(64, n_triples // 8),
+            preds_per_subject=min(6, n_preds),
+            seed=seed,
+        )
     t0 = time.time()
-    store = k2triples.from_id_triples(
-        ds.ids, n_so=ds.n_so, n_subjects=ds.n_subjects,
-        n_objects=ds.n_objects, n_preds=ds.n_preds,
-    )
+    if store is None:
+        store = k2triples.from_id_triples(
+            ds.ids, n_so=ds.n_so, n_subjects=ds.n_subjects,
+            n_objects=ds.n_objects, n_preds=ds.n_preds,
+        )
     if not quiet:
         print(
             f"store: {store.n_triples} triples, {store.n_preds} preds, "
@@ -193,16 +209,18 @@ def run_bench(
                 "--xla_force_host_platform_device_count=N)"
             )
         mesh_shape = meshlib.serve_mesh_shape(n_dev)
-        overrides["mesh"] = jax.make_mesh(mesh_shape, ("data", "model"))
+        overrides["mesh"] = meshlib.make_mesh(mesh_shape, ("data", "model"))
         if not quiet:
             print(f"sharded over mesh {{'data': {mesh_shape[0]}, 'model': {mesh_shape[1]}}}")
     cfg = ExecConfig.from_env(**overrides)
 
     engine = eng.Engine(store)
-    trace = make_trace(
-        ds, n_queries, n_tenants, zipf_a=zipf_a, unbounded=unbounded,
-        select_frac=select_frac, seed=seed + 1,
-    )
+    if trace is None:
+        trace = make_trace(
+            ds, n_queries, n_tenants, zipf_a=zipf_a, unbounded=unbounded,
+            select_frac=select_frac, seed=seed + 1,
+        )
+    n_queries = len(trace)
     # bound per-tenant windows so ~two coalesced batches stay outstanding:
     # the pipeline keeps both buffers fed while latency still means
     # "time through the broker", not "time parked in an unbounded queue"
@@ -225,19 +243,21 @@ def run_bench(
         )
         async with broker:
             # warmup: compile the serve program + prime every op type
+            tw = time.perf_counter()
             await _replay(broker, trace[: min(warmup, len(trace))])
+            warm = time.perf_counter() - tw
             broker.reset_stats()
             if tracer is not None:
                 tracer.clear()
             if metrics is not None:
                 metrics.reset()
             t0 = time.perf_counter()
-            n_done = await _replay(broker, trace)
+            n_done = await _replay(broker, trace, answers)
             wall = time.perf_counter() - t0
-        return broker, broker.stats(), n_done, wall
+        return broker, broker.stats(), n_done, wall, warm
 
     try:
-        broker, stats, n_done, wall = asyncio.run(main())
+        broker, stats, n_done, wall, warm = asyncio.run(main())
         if obs_enabled:
             _export_obs(
                 broker, engine, tracer, metrics,
@@ -253,6 +273,7 @@ def run_bench(
         "mesh": list(mesh_shape) if mesh_shape else None,
         "devices": n_dev,
         "backend": cfg.backend,
+        "interpret": cfg.resolved().interpret,
         "triples": store.n_triples,
         "preds": store.n_preds,
         "tenants": n_tenants,
@@ -266,6 +287,7 @@ def run_bench(
         "donate": cfg.donate_batch and cfg.mesh is None,
         "pred_index_layout": cfg.pred_index_layout,
         "deadline_ms": deadline_ms,
+        "warmup_s": warm,
         "wall_s": wall,
         "qps": n_queries / wall,
         "p50_ms": stats["p50_ms"],
@@ -380,6 +402,7 @@ def main(argv=None) -> None:
              "the p50/qps overhead of tracing",
     )
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     kw = dict(
         n_triples=args.triples, n_preds=args.preds, n_tenants=args.tenants,

@@ -275,9 +275,7 @@ def moe_ffn_shmap(cfg: TransformerCfg, lp, x3, *, mesh, dp_axes, model_axis="mod
         out = jax.lax.psum(out, model_axis)
         return out.reshape(Bl, S, D)
 
-    from repro.compat import shard_map  # local import: keep models jax-pure
-
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(x_spec, {k: lp_specs[k] for k in ("router", "we1", "we3", "we2")}),
         out_specs=x_spec,

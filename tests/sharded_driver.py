@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 
 
 def case_engine():
@@ -29,7 +29,7 @@ def case_engine():
         n_objects=ds.n_objects, n_preds=ds.n_preds,
     )
     T = set(map(tuple, ds.ids.tolist()))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     E = eng.Engine(store)
     plan = E.compile(ServeQ(unbounded=False), ExecConfig.from_env(cap=256, mesh=mesh))
     rng = np.random.default_rng(0)
@@ -93,7 +93,7 @@ def case_engine_pruned():
         n_objects=ds.n_objects, n_preds=ds.n_preds,
     )
     T = set(map(tuple, ds.ids.tolist()))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     E = eng.Engine(store)
     plan_sh = E.compile(ServeQ(), ExecConfig.from_env(cap=128, mesh=mesh))
     plan_1d = E.compile(ServeQ(), ExecConfig.from_env(cap=128))
@@ -153,12 +153,12 @@ def case_compress():
     """int8 EF all-reduce: shared scale is exact-sum; EF kills bias."""
     from repro.dist import compress
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(0)
     g_all = rng.standard_normal((8, 256)).astype(np.float32)
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda g, e: compress.compress_decompress_psum(g, e, "data"),
             mesh=mesh, in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data")),
         )
@@ -190,7 +190,7 @@ def case_sortedset_union():
         ds.ids, n_so=ds.n_so, n_subjects=ds.n_subjects,
         n_objects=ds.n_objects, n_preds=ds.n_preds,
     )
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     T = set(map(tuple, ds.ids.tolist()))
     plan = eng.Engine(store).compile(
         ServeQ(unbounded=False), ExecConfig.from_env(cap=512, mesh=mesh)
@@ -227,7 +227,7 @@ def case_moe_shmap():
 
     ref = tf.moe_ffn(cfg, lp, x.reshape(B * S, D)).reshape(B, S, D)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with mesh:
         got = tf.moe_ffn_shmap(cfg, lp, x, mesh=mesh, dp_axes=("data",))
     np.testing.assert_allclose(

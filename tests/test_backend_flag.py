@@ -153,3 +153,66 @@ def test_env_flip_switches_dispatch(monkeypatch):
         out[be] = k2forest.scan_batch_mixed(meta, f, preds, keys, axes, 32)
     for a, b in zip(tuple(out["jnp"]), tuple(out["pallas"])):
         assert (np.asarray(a) == np.asarray(b)).all()
+
+
+# ---------------------------------------------------------------------------
+# interpret mode on a TPU backend is an error, never a quiet fallback
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def steer_backend(monkeypatch):
+    """Make the backend check report the given platform (no device used)."""
+    from repro.core import query as qapi
+
+    def steer(platform):
+        monkeypatch.setattr(qapi, "_backend", lambda: platform)
+
+    return steer
+
+
+def test_interpret_on_tpu_is_an_error(monkeypatch, steer_backend):
+    from repro.core import engine as eng, k2triples
+    from repro.core.query import ExecConfig, ServeQ
+    from repro.data import rdf
+
+    steer_backend("tpu")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.raises(ValueError, match="interpret mode requested on a TPU"):
+        ExecConfig.from_env()
+    with pytest.raises(ValueError, match="interpret mode requested on a TPU"):
+        ops.pallas_interpret()
+    with pytest.raises(ValueError, match="interpret mode requested on a TPU"):
+        ops.pallas_interpret(True)
+    with pytest.raises(ValueError, match="interpret mode requested on a TPU"):
+        ExecConfig(interpret=True).resolved()
+    with pytest.raises(ValueError, match="interpret mode requested on a TPU"):
+        ops.resolve_exec(ExecConfig(backend="pallas", interpret=True))
+    # the serve path: compiling a plan under such a config refuses
+    ds = rdf.generate(200, n_subjects=20, n_preds=3, n_objects=20, seed=5)
+    store = k2triples.from_id_triples(
+        ds.ids, n_so=ds.n_so, n_subjects=ds.n_subjects,
+        n_objects=ds.n_objects, n_preds=ds.n_preds,
+    )
+    with pytest.raises(ValueError, match="interpret mode requested on a TPU"):
+        eng.Engine(store).compile(ServeQ(), ExecConfig(interpret=True))
+    # compiled kernels are what a TPU resolves to otherwise
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert ExecConfig.from_env().interpret is False
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
+    assert ExecConfig.from_env().interpret is False
+    assert ExecConfig().resolved().interpret is False
+    assert ops.pallas_interpret() is False
+    assert ops.resolve_exec(ExecConfig(backend="pallas")) == ("pallas", False)
+
+
+def test_interpret_default_refuses_other_backends(steer_backend):
+    from repro.core.query import ExecConfig, default_interpret
+
+    steer_backend("gpu")
+    with pytest.raises(RuntimeError, match="need a TPU backend"):
+        default_interpret()
+    with pytest.raises(RuntimeError, match="need a TPU backend"):
+        ExecConfig().resolved()
+    steer_backend("cpu")
+    assert default_interpret() is True

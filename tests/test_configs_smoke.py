@@ -110,7 +110,9 @@ def test_program_builders_smoke_lower(arch_id, shape_id):
     """Program builders produce lowerable cells on a 1x1 mesh (smoke shapes)."""
     from repro.launch import programs
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
     prog = programs.build(arch_id, shape_id, mesh, smoke=True)
     with mesh:
         lowered = jax.jit(prog.fn, in_shardings=prog.in_shardings).lower(*prog.in_specs)

@@ -122,3 +122,38 @@ def test_pad_preds_inert(store):
 def test_pad_preds_noop_when_aligned(store):
     st, _ = store
     assert eng.pad_preds(st.forest, 3) is st.forest  # 6 % 3 == 0
+
+
+@pytest.mark.parametrize("backend", ["pallas", "jnp"])
+def test_serve_step_out_of_range_preds(store, backend):
+    """A client's predicate or entity id outside the store answers empty on
+    every bounded op of the serve step (with the SP/OP index in the
+    program), on both backends; real lanes of the same batch are
+    unaffected."""
+    st, ds = store
+    T = _truth(ds)
+    index, pmeta = st.pred_index.select("dac")
+    step = eng.make_serve_step(st.meta, cap=64, backend=backend, pmeta=pmeta)
+    s_, p_, o_ = (int(x) for x in ds.ids[0])
+    bad = [st.n_preds + 1, st.n_preds + 3, 9, 10**6, 0, -7]  # 1-based ids
+    three = [eng.OP_CHECK, eng.OP_ROW, eng.OP_COL]
+    ops, ss, ps, os_ = three * len(bad), [s_] * 18, [], [o_] * 18
+    ps = [b for b in bad for _ in range(3)]
+    for e in (0, -3, st.meta.side + 1, 10**6):  # entities past the matrix
+        ops += three
+        ss += [e, e, s_]
+        ps += [p_] * 3
+        os_ += [e, o_, e]
+    q = _batch(ops + three, ss + [s_] * 3, ps + [p_] * 3, os_ + [o_] * 3)
+    r = step(st.forest, q, index)
+    n = len(ops)
+    assert not np.asarray(r.hit)[:n].any()
+    assert not np.asarray(r.valid)[:n].any()
+    assert (np.asarray(r.count)[:n] == 0).all()
+    assert not np.asarray(r.overflow)[:n].any()
+    ids, valid = np.asarray(r.ids), np.asarray(r.valid)
+    assert bool(np.asarray(r.hit)[n])
+    assert ids[n + 1][valid[n + 1]].tolist() == sorted(
+        oo for (ss, pp, oo) in T if ss == s_ and pp == p_)
+    assert ids[n + 2][valid[n + 2]].tolist() == sorted(
+        ss for (ss, pp, oo) in T if pp == p_ and oo == o_)

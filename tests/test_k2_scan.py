@@ -112,6 +112,99 @@ def test_k2_scan_empty_trees():
     assert not np.asarray(r.overflow).any()
 
 
+def test_k2_scan_dead_lanes_and_lane_blocks():
+    """Lanes with pred < 0 answer empty (no overflow, even at a cap below
+    the root arity) on every backend, and a batch larger than one
+    1024-lane SMEM block runs as padded blocks."""
+    side = 100
+    rng = np.random.default_rng(11)
+    coords = [(rng.integers(0, side, 400), rng.integers(0, side, 400))
+              for _ in range(2)]
+    meta, f = _forest(coords, side)
+    dense = dense_from_coords(coords, meta.side)
+    q = 2100
+    preds = np.full(q, -1)
+    live = rng.choice(q - 1, 39, replace=False).tolist() + [q - 1]
+    preds[live] = rng.integers(0, 2, len(live))
+    keys = rng.integers(0, side, q)
+    axes = rng.integers(0, 2, q)
+    for cap in (2, 16):
+        r = _run_all_backends(meta, f, preds, keys, axes, cap)
+        dead = preds < 0
+        assert not np.asarray(r.valid)[dead].any()
+        assert not np.asarray(r.overflow)[dead].any()
+        assert (np.asarray(r.count)[dead] == 0).all()
+    for i in live:
+        d = dense[preds[i]]
+        truth = np.nonzero(d[keys[i]] if axes[i] == 0 else d[:, keys[i]])[0]
+        got = np.asarray(r.ids[i])[np.asarray(r.valid[i])]
+        assert got.tolist() == truth[: len(got)].tolist()
+        assert bool(r.overflow[i]) or len(got) == len(truth)
+
+
+def test_out_of_range_preds_are_dead():
+    """Predicate ids past the forest answer empty on every traversal entry
+    and backend: ids in the arena's padding rows, past its last row, and
+    negative.  The kernel's own guard holds too when it is handed a row
+    past the arena directly (on the chip that read would leave the array)."""
+    from repro.kernels import ops
+
+    side = 100
+    rng = np.random.default_rng(12)
+    coords = [(rng.integers(0, side, 400), rng.integers(0, side, 400))
+              for _ in range(2)]
+    meta, f = _forest(coords, side)
+    rows = f.t_words.shape[0]
+    assert f.n_preds == 2 and rows == 8  # one 8-row tile, 6 padding rows
+    bad = jnp.asarray([2, 7, rows, rows + 1, 1000, 2**30, -5], jnp.int32)
+    n = bad.shape[0]
+    keys = jnp.asarray(rng.integers(0, side, n), jnp.int32)
+    axes = jnp.asarray(np.arange(n) % 2, jnp.int32)
+    for backend in ("pallas", "jnp"):
+        r = k2forest.scan_batch_mixed(meta, f, bad, keys, axes, 16, backend)
+        assert not np.asarray(r.valid).any() and not np.asarray(r.overflow).any()
+        assert (np.asarray(r.count) == 0).all()
+        pr = k2forest.range_scan_batch(meta, f, bad, 16, backend)
+        assert not np.asarray(pr.valid).any() and not np.asarray(pr.overflow).any()
+        x = k2forest.scan_rebind_batch(meta, f, bad, keys, axes, bad, axes,
+                                       4, 4, backend)
+        assert not np.asarray(x[1]).any() and not np.asarray(x[5]).any()
+    assert not np.asarray(k2forest.check(meta, f, bad, keys, keys)).any()
+    # the kernel itself, bypassing the dispatch's id check
+    past = jnp.asarray([rows, rows + 9, 1000, -1], jnp.int32)
+    ids, valid, count, ovf = ops.k2_scan_forest(
+        meta, f, past, keys[:4], axes[:4], cap=16, interpret=True)
+    assert not np.asarray(valid).any() and not np.asarray(ovf).any()
+
+
+def test_kernels_refuse_untiled_arenas():
+    """An arena that is not whole (8, 128) tiles is refused by name, never
+    read past its end."""
+    from repro.kernels import k2_scan
+
+    side = 64
+    meta, f = _forest([(np.arange(side), np.arange(side))], side)
+    q = jnp.zeros((4,), jnp.int32)
+    with pytest.raises(ValueError, match="k2_scan: .* not whole"):
+        k2_scan.k2_scan(meta, q, q, q, f.t_words[:5], f.t_rank[:5],
+                        f.l_words[:5], f.ones_before[:5], f.level_start[:5],
+                        cap=8, interpret=True)
+
+
+def test_forest_arena_is_tiled():
+    """``build_forest`` lays every arena out in whole (8, 128) tiles and
+    keeps the logical predicate count in ``nnz``."""
+    side = 300
+    rng = np.random.default_rng(13)
+    coords = [(rng.integers(0, side, 500), rng.integers(0, side, 500))
+              for _ in range(11)]
+    meta, f = _forest(coords, side)
+    assert f.n_preds == 11
+    for a in f[:-1]:
+        assert a.shape[0] == 16 and a.shape[1] % 128 == 0, a.shape
+    assert not np.asarray(f.t_words[11:]).any()  # padding rows: empty trees
+
+
 def test_k2_scan_full_rows():
     """A fully-populated matrix: every scan returns a full line (or caps)."""
     side = 64

@@ -56,7 +56,7 @@ def _random_store(rng, n_preds: int, *, hub_degree: int | None = None):
     )
 
 
-def _kernel_call(bi, layout, rows, cap, block_q):
+def _kernel_call(bi, layout, rows, cap):
     dev, meta = bi.select(layout)
     if layout == "dac":
         return pred_gather.pred_gather_dac(
@@ -64,12 +64,12 @@ def _kernel_call(bi, layout, rows, cap, block_q):
             dev.frank, levels=meta.levels,
             level_byte_start=meta.level_byte_start,
             flag_word_start=meta.flag_word_start, deg_width=meta.deg_width,
-            rows_per_block=meta.rows_per_block, cap=cap, block_q=block_q,
+            rows_per_block=meta.rows_per_block, cap=cap,
             interpret=True,
         )
     return pred_gather.pred_gather(
         jnp.asarray(rows), dev.offsets, dev.words,
-        bytes_per_pred=meta.bytes_per_pred, cap=cap, block_q=block_q,
+        bytes_per_pred=meta.bytes_per_pred, cap=cap,
         interpret=True,
     )
 
@@ -98,7 +98,7 @@ def test_pred_gather_kernel_vs_refs(n_preds, cap, layout):
         bi = _random_store(rng, n_preds)
         rows = rng.integers(0, R, 64).astype(np.int32)
         rows[:2] = (0, 1)  # force the degree-0 entity and the hub into view
-        kout = _kernel_call(bi, layout, rows, cap, block_q=32)
+        kout = _kernel_call(bi, layout, rows, cap)
         rout = _ref_call(bi, layout, rows, cap)
         tout = predindex._gather_traced(
             bi.select(layout)[1], bi.select(layout)[0], rows, cap
@@ -125,9 +125,9 @@ def test_pred_gather_dac_multi_level(cap):
     assert bi.meta.levels >= 2, bi.meta  # the whole point of this test
     rows = rng.integers(0, R, 64).astype(np.int32)
     rows[:2] = (0, 1)
-    kout = _kernel_call(bi, "dac", rows, cap, block_q=32)
+    kout = _kernel_call(bi, "dac", rows, cap)
     rout = _ref_call(bi, "dac", rows, cap)
-    fout = _kernel_call(bi, "fixed", rows, cap, block_q=32)
+    fout = _kernel_call(bi, "fixed", rows, cap)
     assert_results_identical(tuple(kout), tuple(rout), "kernel-vs-ref")
     assert_results_identical(tuple(kout), tuple(fout), "dac-vs-fixed")
     ids, valid, count, ovf = (np.asarray(a) for a in kout)

@@ -54,6 +54,20 @@ def test_serve_mesh_shape_model_max():
 # ---------------------------------------------------------------------------
 
 
+def test_meshes_have_auto_axes():
+    from jax.sharding import AxisType
+
+    m = meshlib.make_mesh((1, 1), ("data", "model"))
+    assert m.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
+def test_peaks_table_is_keyed_by_device_kind():
+    p = meshlib.peaks("TPU v5 lite")
+    assert (p.flops_bf16, p.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        meshlib.peaks("cpu")
+
+
 def test_sharded_single_device_errors():
     import jax
 
