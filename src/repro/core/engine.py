@@ -116,24 +116,17 @@ def take_lanes(q: ServeBatch, idx) -> ServeBatch:
 
 
 def host_result(r: ServeResult, *, unbounded: bool = True) -> ServeResult:
-    """ONE blocking device->host fetch of a ``ServeResult`` (numpy fields).
+    """ONE blocking device->host fetch of a ``ServeResult`` (numpy fields):
+    :func:`wait_result`, then the copy of each field, one array at a time.
 
     This is where a streamed decode pays its sync; calling it on batch N
     after submitting batch N+1 (``Plan.submit``) is the double-buffering
     pattern.  ``unbounded=False`` skips the ``u_*`` block — by far the
     largest transfer (``[B, L, cap]``) — for batches the caller knows
-    carry no unbounded-``?P`` lanes.
+    carry no unbounded-``?P`` lanes; its fields come back as empty
+    ``[B, 0, ...]`` placeholders.
     """
-    t = obs.STATE.tracer
-    if t is None:
-        return _host_result(r, unbounded)
-    with t.span("engine.fetch", cat="engine",
-                b=int(r.ids.shape[0]), unbounded=unbounded):
-        return _host_result(r, unbounded)
-
-
-def _host_result(r: ServeResult, unbounded: bool) -> ServeResult:
-    jax.block_until_ready(r.ids)
+    wait_result(r)
     b = r.ids.shape[0]
     if unbounded:
         return ServeResult(*(np.asarray(a) for a in r))
@@ -148,6 +141,12 @@ def _host_result(r: ServeResult, unbounded: bool) -> ServeResult:
     )
 
 
+def wait_result(r: ServeResult) -> None:
+    """Block until the device has computed every field of ``r`` (a
+    :func:`host_result` after it only copies)."""
+    jax.block_until_ready(r)
+
+
 def decode_lane(op: int, r: ServeResult, i: int):
     """Decode ONE lane of a host-side ``ServeResult`` into its python-level
     answer (the per-op shapes ``_PatternExec._decode`` returns):
@@ -160,14 +159,6 @@ def decode_lane(op: int, r: ServeResult, i: int):
     tenant's queries as their lanes decode instead of materializing a
     batch-level result object.
     """
-    t = obs.STATE.tracer
-    if t is None:
-        return _decode_lane(op, r, i)
-    with t.span("plan.decode_lane", cat="plan", op=int(op)):
-        return _decode_lane(op, r, i)
-
-
-def _decode_lane(op: int, r: ServeResult, i: int):
     if op == OP_CHECK:
         return bool(r.hit[i])
     if op in (OP_ROW, OP_COL, OP_S_ANY_O):
@@ -234,22 +225,24 @@ def _serve_local(
     """
     b = q.op.shape[0]
     is_check = q.op == OP_CHECK
-    hit = k2forest.check(
-        meta, f, q.p - 1, q.s - 1, q.o - 1
-    ) & is_check
+    with jax.named_scope("check"):
+        hit = k2forest.check(
+            meta, f, q.p - 1, q.s - 1, q.o - 1
+        ) & is_check
     axes = jnp.where(q.op == OP_COL, 1, 0).astype(jnp.int32)
     key = jnp.where(q.op == OP_COL, q.o, q.s) - 1
     scan_lane = (q.op == OP_ROW) | (q.op == OP_COL)
     # lanes that are not scans park on pred -1: the traversal skips them,
     # as it skips a predicate or key outside the store (k2forest.live_lanes)
-    r = k2forest.scan_batch_mixed(
-        meta, f, jnp.where(scan_lane, q.p - 1, -1), key, axes,
-        cap, backend,
-    )
-    valid = r.valid & scan_lane[:, None]
-    ids = jnp.where(valid, r.ids + 1, 0)
-    count = jnp.where(scan_lane, r.count, 0)
-    overflow = r.overflow & scan_lane
+    with jax.named_scope("scan"):
+        r = k2forest.scan_batch_mixed(
+            meta, f, jnp.where(scan_lane, q.p - 1, -1), key, axes,
+            cap, backend, name="k2_scan_bound",
+        )
+        valid = r.valid & scan_lane[:, None]
+        ids = jnp.where(valid, r.ids + 1, 0)
+        count = jnp.where(scan_lane, r.count, 0)
+        overflow = r.overflow & scan_lane
 
     if u_width <= 0:
         return ServeResult(
@@ -260,26 +253,29 @@ def _serve_local(
             u_count=jnp.zeros((b, 0), jnp.int32),
         )
 
-    is_u_pair, is_u_check, u_key, u_axis, cpreds, cvalid, ctrunc = (
-        _u_candidates(q, f, u_width, index, pmeta, backend)
-    )
+    with jax.named_scope("u_candidates"):
+        is_u_pair, is_u_check, u_key, u_axis, cpreds, cvalid, ctrunc = (
+            _u_candidates(q, f, u_width, index, pmeta, backend)
+        )
     preds_f = jnp.where(cvalid, cpreds, 0).reshape(b * u_width)
     keys_f = jnp.repeat(u_key, u_width)
     pair_valid = cvalid & is_u_pair[:, None]
 
     # pair lanes: one pruned mixed scan replaces the P-way broadcast sweep
-    ru = k2forest.scan_batch_mixed(
-        meta, f, jnp.where(pair_valid.reshape(-1), preds_f, -1), keys_f,
-        jnp.repeat(u_axis, u_width), cap, backend,
-    )
-    u_valid = ru.valid.reshape(b, u_width, cap) & pair_valid[:, :, None]
-    u_ids = jnp.where(u_valid, ru.ids.reshape(b, u_width, cap) + 1, 0)
-    u_count = jnp.where(pair_valid, ru.count.reshape(b, u_width), 0)
-    u_preds = jnp.where(pair_valid, cpreds + 1, 0)
-    overflow = overflow | (
-        is_u_pair
-        & ((ru.overflow.reshape(b, u_width) & pair_valid).any(axis=1) | ctrunc)
-    )
+    with jax.named_scope("u_scan"):
+        ru = k2forest.scan_batch_mixed(
+            meta, f, jnp.where(pair_valid.reshape(-1), preds_f, -1), keys_f,
+            jnp.repeat(u_axis, u_width), cap, backend, name="k2_scan_u",
+        )
+        u_valid = ru.valid.reshape(b, u_width, cap) & pair_valid[:, :, None]
+        u_ids = jnp.where(u_valid, ru.ids.reshape(b, u_width, cap) + 1, 0)
+        u_count = jnp.where(pair_valid, ru.count.reshape(b, u_width), 0)
+        u_preds = jnp.where(pair_valid, cpreds + 1, 0)
+        overflow = overflow | (
+            is_u_pair
+            & ((ru.overflow.reshape(b, u_width) & pair_valid).any(axis=1)
+               | ctrunc)
+        )
 
     # S?PO lanes: check candidates, compact matching predicate ids into ids.
     # NOTE this intentionally diverges from predindex.check_pruned_batch
@@ -289,18 +285,19 @@ def _serve_local(
     # must honor it.  Keep the three gather→check/scan→mask copies (here,
     # the sharded _local, predindex.*_pruned_batch) in sync when touching
     # the contract.
-    hitm = k2forest.check(
-        meta, f, preds_f,
-        jnp.repeat(q.s - 1, u_width),
-        jnp.repeat(q.o - 1, u_width),
-    ).reshape(b, u_width) & cvalid & is_u_check[:, None]
-    valid5, count5, ovf5, (ids5,) = jax.vmap(
-        lambda v, a: _compact(v, cap, a)
-    )(hitm, jnp.where(hitm, cpreds + 1, 0))
-    ids = jnp.where(is_u_check[:, None], ids5, ids)
-    valid = jnp.where(is_u_check[:, None], valid5, valid)
-    count = jnp.where(is_u_check, count5, count)
-    overflow = overflow | (is_u_check & (ovf5 | ctrunc))
+    with jax.named_scope("u_compact"):
+        hitm = k2forest.check(
+            meta, f, preds_f,
+            jnp.repeat(q.s - 1, u_width),
+            jnp.repeat(q.o - 1, u_width),
+        ).reshape(b, u_width) & cvalid & is_u_check[:, None]
+        valid5, count5, ovf5, (ids5,) = jax.vmap(
+            lambda v, a: _compact(v, cap, a)
+        )(hitm, jnp.where(hitm, cpreds + 1, 0))
+        ids = jnp.where(is_u_check[:, None], ids5, ids)
+        valid = jnp.where(is_u_check[:, None], valid5, valid)
+        count = jnp.where(is_u_check, count5, count)
+        overflow = overflow | (is_u_check & (ovf5 | ctrunc))
 
     return ServeResult(
         hit=hit, ids=ids, valid=valid, count=count, overflow=overflow,
@@ -484,7 +481,7 @@ def make_sharded_serve_step(
         pair_mine = mine_u & is_u_pair[:, None]
         ru = k2forest.scan_batch_mixed(
             meta, f_loc, jnp.where(pair_mine.reshape(-1), preds_f, -1), keys_f,
-            jnp.repeat(u_axis, u_width), cap, backend,
+            jnp.repeat(u_axis, u_width), cap, backend, name="k2_scan_u",
         )
         uv_loc = ru.valid.reshape(b, u_width, cap) & pair_mine[:, :, None]
         u_ids = jax.lax.psum(

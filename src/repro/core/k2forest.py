@@ -270,7 +270,7 @@ def _axis_scan_traced(
 
 def scan_batch_mixed(
     meta: K2Meta, f: K2Forest, preds, keys, axes, cap: int,
-    backend: str | None = None,
+    backend: str | None = None, *, name: str = "k2_scan",
 ) -> QueryResult:
     """Batched mixed row/col scans: axes[i]==0 -> row (S,P,?O), 1 -> col.
 
@@ -285,7 +285,9 @@ def scan_batch_mixed(
     ``None`` falls back to the legacy ``REPRO_SCAN_BACKEND`` env
     resolution.  "pallas" routes to the batched ``kernels.k2_scan`` TPU
     kernel, "jnp" to the vmapped level-synchronous traversal below.  Both
-    produce bit-identical QueryResults (tests/test_k2_scan.py).
+    produce bit-identical QueryResults (tests/test_k2_scan.py).  ``name``
+    names the kernel launch in the compiled program and its profiles (the
+    serve step's ``k2_scan_bound`` and ``k2_scan_u``).
     """
     from repro.kernels import ops  # deferred: core must import without pallas
 
@@ -293,7 +295,7 @@ def scan_batch_mixed(
     be, interp = ops.resolve_exec(backend)
     if be == "pallas":
         ids, valid, count, overflow = ops.k2_scan_forest(
-            meta, f, preds, keys, axes, cap=cap, interpret=interp
+            meta, f, preds, keys, axes, cap=cap, interpret=interp, name=name
         )
         return QueryResult(ids=ids, valid=valid, count=count, overflow=overflow)
     return jax.vmap(lambda p, x, a: _axis_scan_traced(meta, f, p, x, a, cap))(

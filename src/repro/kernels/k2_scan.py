@@ -259,7 +259,7 @@ def lane_pad(qp: int, *vecs):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("meta", "cap", "interpret")
+    jax.jit, static_argnames=("meta", "cap", "interpret", "name")
 )
 def k2_scan(
     meta: K2Meta,
@@ -274,11 +274,14 @@ def k2_scan(
     *,
     cap: int,
     interpret: bool = False,
+    name: str = "k2_scan",
 ):
     """Batched mixed row/col scans over a K2Forest arena.
 
     Returns ``(ids, valid, count, overflow)`` with shapes
-    ``(Q, cap) / (Q, cap) / (Q,) / (Q,)``.
+    ``(Q, cap) / (Q, cap) / (Q,) / (Q,)``.  ``name`` is the launch's name in
+    the compiled program and in a profile (``%<name>.N``), so that callers
+    that launch the kernel for different purposes can be told apart.
     """
     (q,) = preds.shape
     bq, qp = tiles.lane_blocks(q)
@@ -331,6 +334,7 @@ def k2_scan(
         ],
         compiler_params=_params(),
         interpret=interpret,
+        name=name,
     )(p, k, x, *arrs)
     return decode(out, q, cap)
 

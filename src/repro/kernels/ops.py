@@ -146,6 +146,7 @@ def k2_scan_forest(
     *,
     cap: int,
     interpret: bool | None = None,
+    name: str = "k2_scan",
 ):
     """Kernel-backed batched mixed row/col scan over a K2Forest.
 
@@ -153,14 +154,14 @@ def k2_scan_forest(
     here when the scan backend is "pallas").  Lanes whose pred is not a
     row of the arena are dead and answer empty.  Returns (ids, valid, count, overflow).
     ``interpret=None`` defers to the legacy env flag; the compiled-plan
-    path always passes an explicit bool.
+    path always passes an explicit bool.  ``name`` names the launch.
     """
     return _ks.k2_scan(
         meta, jnp.asarray(preds, jnp.int32), jnp.asarray(keys, jnp.int32),
         jnp.asarray(axes, jnp.int32),
         forest.t_words, forest.t_rank, forest.l_words,
         forest.ones_before, forest.level_start,
-        cap=cap, interpret=pallas_interpret(interpret),
+        cap=cap, interpret=pallas_interpret(interpret), name=name,
     )
 
 

@@ -217,19 +217,27 @@ class _Req:
 
 @dataclasses.dataclass
 class _BatchMeta:
-    """Timeline of one dispatched batch (``time.perf_counter`` seconds):
-    coalesce ``[tc0, tc1]`` → encode+dispatch ``[td0, td1]`` → inflight →
-    fetch ``[tf0, tf1]`` → decode/deliver.  Feeds the retroactive trace
-    spans emitted once the batch fully delivers."""
+    """Timeline of one dispatched batch (``time.perf_counter_ns``), stamped
+    whether tracing is on or off: coalesce ``[tc0, tc1]`` → encode+dispatch
+    ``[td0, td1]`` → inflight ``[td1, tf0]`` → the fetch ``[tf0, tf1]`` in
+    stages that meet end to end: handoff to a worker thread ``[tf0, tw0]``,
+    device wait ``[tw0, tw1]``, copy ``[tw1, tw2]``, delta merge
+    ``[tw2, tw3]`` (empty for a static store), resume on the event loop
+    ``[tw3, tf1]`` → decode/deliver.  Feeds the retroactive trace spans
+    emitted once the batch fully delivers."""
 
     bid: int
     n_padded: int
-    tc0: float = 0.0
-    tc1: float = 0.0
-    td0: float = 0.0
-    td1: float = 0.0
-    tf0: float = 0.0
-    tf1: float = 0.0
+    tc0: int = 0
+    tc1: int = 0
+    td0: int = 0
+    td1: int = 0
+    tf0: int = 0
+    tw0: int = 0
+    tw1: int = 0
+    tw2: int = 0
+    tw3: int = 0
+    tf1: int = 0
 
 
 @dataclasses.dataclass
@@ -316,6 +324,10 @@ class ServeBroker:
         self._queue_peak = 0
         self._seq = 0  # per-query trace ids
         self._bid = 0  # batch ids
+        # trace tracks of the batch stages (see ``_trace_batch``)
+        self._slots = tuple(
+            f"batch-slot-{i}" for i in range(2 * coalesce.max_inflight)
+        )
         self._retry_cfgs: set[ExecConfig] = set()  # cap levels ever compiled
 
     # -- lifecycle ------------------------------------------------------
@@ -622,14 +634,14 @@ class ServeBroker:
 
     async def _collect(self, *, block: bool):
         """Coalesce: returns ``(reqs, tc0, tc1)`` — the collected batch
-        plus the perf-counter window the coalesce wait spanned."""
+        plus the ``perf_counter_ns`` window the coalesce wait spanned."""
         pol = self.coalesce
         while not self._queue:
             if not block or self._draining:
-                return [], 0.0, 0.0
+                return [], 0, 0
             self._wake.clear()
             await self._wake.wait()
-        tc0 = time.perf_counter()
+        tc0 = time.perf_counter_ns()
         # deadline of the OLDEST pending request governs the flush
         deadline = self._queue[0].t_submit + pol.max_delay_s
         while len(self._queue) < pol.max_batch and not self._draining:
@@ -648,10 +660,14 @@ class ServeBroker:
         else:
             self._c["flush_deadline"].inc()
         n = min(len(self._queue), pol.max_batch)
-        return [self._queue.popleft() for _ in range(n)], tc0, time.perf_counter()
+        reqs = [self._queue.popleft() for _ in range(n)]
+        return reqs, tc0, time.perf_counter_ns()
 
-    def _dispatch(self, reqs: list[_Req], tc0: float = 0.0, tc1: float = 0.0):
-        td0 = time.perf_counter()
+    def _dispatch(self, reqs: list[_Req], tc0: int = 0, tc1: int = 0):
+        bid = self._bid
+        t = obs.STATE.tracer
+        td0 = time.perf_counter_ns()
+        live = _stage(t, "broker.dispatch", td0, self._slot(bid), bid)
         qb = self._encode(reqs, self._pad_to)
         # pin the dynamic view AT dispatch: the static lane answers this
         # batch against lanes sanitized to the static extents, and decode
@@ -662,10 +678,12 @@ class ServeBroker:
         except StaleEpoch:  # a compaction swapped under the base plan
             self._refresh_base_plan()
             raw, view = self._submit_dyn(self.base_plan, qb)
+        td1 = time.perf_counter_ns()
+        if live is not None:
+            t.end(live, t1=td1)
         meta = _BatchMeta(
-            bid=self._bid, n_padded=int(qb.op.shape[0]),
-            tc0=tc0 or td0, tc1=tc1 or td0, td0=td0,
-            td1=time.perf_counter(),
+            bid=bid, n_padded=int(qb.op.shape[0]),
+            tc0=tc0 or td0, tc1=tc1 or td0, td0=td0, td1=td1,
         )
         self._bid += 1
         self._inflight.append((raw, reqs, meta, qb, view))
@@ -679,7 +697,7 @@ class ServeBroker:
             m.gauge("broker.queue_depth").set(len(self._queue))
             h = m.histogram("broker.queue_wait_ms", LATENCY_MS_BUCKETS)
             for r in reqs:
-                h.observe((td0 - r.t_submit) * 1e3)
+                h.observe((td0 * 1e-9 - r.t_submit) * 1e3)
 
     def _encode(self, reqs: list[_Req], pad_to: int) -> eng.ServeBatch:
         n = max(pad_to, self._padded_batch(len(reqs)))
@@ -701,6 +719,9 @@ class ServeBroker:
         qb_run = qb if view is None else view.sanitize_batch(qb)
         return plan.submit(qb_run), view
 
+    def _slot(self, bid: int) -> str:
+        return self._slots[bid % len(self._slots)]
+
     def _padded_batch(self, b: int) -> int:
         """pow2 bucket (>= 8), then data-axis divisibility when sharded."""
         n = 8
@@ -717,21 +738,44 @@ class ServeBroker:
     async def _deliver(self, raw, reqs: list[_Req], meta: _BatchMeta,
                        qb: eng.ServeBatch, view):
         has_u = any(r.op in eng._UNBOUNDED_OPS for r in reqs)
-        meta.tf0 = time.perf_counter()
+        slot = self._slot(meta.bid)
+        meta.tf0 = time.perf_counter_ns()
 
         # the blocking device->host fetch (and the host-side delta merge,
         # when the store is dynamic) runs off-loop so submitters keep
-        # filling the next batch while this one decodes
+        # filling the next batch while this one decodes.  Each stage that
+        # begins and ends on this thread is also a live span there when
+        # tracing is on (so ``ObsConfig(device_annotations=True)`` puts it
+        # in the profiler's own trace)
         def fetch():
+            t = obs.STATE.tracer
+            meta.tw0 = time.perf_counter_ns()
+            live = _stage(t, "broker.device_wait", meta.tw0, slot, meta.bid)
+            eng.wait_result(raw)
+            meta.tw1 = time.perf_counter_ns()
+            if live is not None:
+                t.end(live, t1=meta.tw1)
+                live = _stage(t, "broker.copy", meta.tw1, slot, meta.bid)
+            # the result is ready: what is left of the fetch is the copy
             host = eng.host_result(raw, unbounded=has_u and self.unbounded)
+            meta.tw2 = meta.tw3 = time.perf_counter_ns()
+            if live is not None:
+                # the u_* placeholders of a bounded fetch hold no bytes
+                fetched = [a.nbytes for a in host if a.nbytes]
+                t.end(live, t1=meta.tw2, bytes=sum(fetched),
+                      arrays=len(fetched))
             if view is not None:
+                live = _stage(t, "broker.merge", meta.tw2, slot, meta.bid)
                 # merge against the ORIGINAL (unsanitized) lane constants:
                 # lanes masked off the device get delta-only answers
                 host = view.merge_lanes(qb.op, qb.s, qb.p, qb.o, host)
+                meta.tw3 = time.perf_counter_ns()
+                if live is not None:
+                    t.end(live, t1=meta.tw3)
             return host
 
         host = await asyncio.to_thread(fetch)
-        meta.tf1 = time.perf_counter()
+        meta.tf1 = time.perf_counter_ns()
         retry_tenants = {
             reqs[i].tenant
             for i in np.nonzero(host.overflow[: len(reqs)])[0]
@@ -751,44 +795,39 @@ class ServeBroker:
 
     def _trace_batch(self, reqs: list[_Req], meta: _BatchMeta):
         """Emit the batch's retroactive spans now that every timestamp of
-        its lifetime is known.
+        its lifetime is known: a fixed number per batch, none per lane.
 
         Batch stages land as complete spans on a bounded pool of
         ``batch-slot-*`` tracks (slot = ``bid`` mod ``2 * max_inflight``
         — the inflight bound guarantees a slot's previous occupant fully
-        delivered before reuse, so same-track spans never overlap).  Each
-        query's lifetime lands as Chrome *async* events keyed by its
-        ``seq``, phases nested by time under one ``query`` umbrella:
-        queue → dispatch → inflight → fetch → decode.
+        delivered before reuse, so same-track spans never overlap), beside
+        the live ``broker.dispatch``, ``broker.device_wait``,
+        ``broker.copy`` and ``broker.merge`` spans recorded as they ran.
+        Each query's lifetime lands as one Chrome *async* ``query`` span
+        keyed by its ``seq`` (its ``bid`` links it to the batch's stages),
+        with its ``queue`` phase (submit to dispatch) nested under it.
         """
         t = obs.STATE.tracer
-        ns = lambda sec: int(sec * 1e9)  # noqa: E731 — perf_counter s -> ns
-        t_end = time.perf_counter()
-        slot = f"batch-slot-{meta.bid % (2 * self.coalesce.max_inflight)}"
+        t_end = time.perf_counter_ns()
+        slot = self._slot(meta.bid)
         occupancy = len(reqs) / meta.n_padded
-        t.add("broker.batch", ns(meta.tc0), ns(t_end), tid=slot, cat="broker",
+        t.add("broker.batch", meta.tc0, t_end, tid=slot, cat="broker",
               bid=meta.bid, lanes=len(reqs), padded=meta.n_padded,
               occupancy=round(occupancy, 4))
         for name, a, b in (
             ("broker.coalesce", meta.tc0, meta.tc1),
-            ("broker.dispatch", meta.td0, meta.td1),
             ("broker.inflight", meta.td1, meta.tf0),
-            ("broker.fetch", meta.tf0, meta.tf1),
+            ("broker.handoff", meta.tf0, meta.tw0),
+            ("broker.resume", meta.tw3, meta.tf1),
             ("broker.decode_deliver", meta.tf1, t_end),
         ):
-            t.add(name, ns(a), ns(b), tid=slot, cat="broker", bid=meta.bid)
+            t.add(name, a, b, tid=slot, cat="broker", bid=meta.bid)
         for i, r in enumerate(reqs):
-            td = r.t_deliver or t_end
-            t.add_async("query", r.seq, ns(r.t_submit), ns(td),
+            t0 = int(r.t_submit * 1e9)  # perf_counter s -> ns
+            td = int(r.t_deliver * 1e9) if r.t_deliver else t_end
+            t.add_async("query", r.seq, t0, td,
                         tenant=r.tenant, op=r.op, lane=i, bid=meta.bid)
-            for name, a, b in (
-                ("queue", r.t_submit, meta.td0),
-                ("dispatch", meta.td0, meta.td1),
-                ("inflight", meta.td1, meta.tf0),
-                ("fetch", meta.tf0, meta.tf1),
-                ("decode", meta.tf1, td),
-            ):
-                t.add_async(name, r.seq, ns(a), ns(min(b, td)))
+            t.add_async("queue", r.seq, t0, min(meta.td0, td))
 
     def _resolve(self, r: _Req, value):
         st = self._tenants[r.tenant]
@@ -982,6 +1021,14 @@ class ServeBroker:
                 self._encode([], 0)
             )
         return out
+
+
+def _stage(t, name: str, t0: int, slot: str, bid: int):
+    """Open batch ``bid``'s live stage span ``name`` at ``t0`` on its slot's
+    track; ``None`` with tracing off (``t`` is ``obs.STATE.tracer``)."""
+    if t is None:
+        return None
+    return t.begin(name, t0=t0, tid=slot, cat="broker", bid=bid)
 
 
 def _ms(v: float | None) -> float | None:

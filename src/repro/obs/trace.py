@@ -5,8 +5,11 @@ spans stamped with the monotonic clock (``time.perf_counter_ns`` — the
 same clock base the broker's latency samples use), recorded either live
 (``begin``/``end`` or the ``span`` context manager) or retroactively
 (``add``/``add_async`` with explicit timestamps — how the broker emits
-per-query phase spans at delivery time, when every timestamp of the
-batch is known).
+the stages of a batch that span an ``await``, and its per-query spans,
+at delivery time, when every timestamp of the batch is known).  A live
+span may take its ends from stamps the caller already took
+(``begin(t0=)``, ``end(t1=)``), so live and retroactive stages of one
+batch meet end to end.
 
 Design rules:
 
@@ -16,10 +19,15 @@ Design rules:
   tracer method calls, no allocation (``tests/test_obs.py`` tripwires
   this the same way ``test_no_env_read_inside_plan_call`` bans env reads
   in compiled plan calls).
-* **Recording never blocks the serve path.**  A record is a dict append
-  into a pre-sized ring under a (practically uncontended) lock; when the
-  ring wraps, the OLDEST spans are dropped and counted (``dropped``) —
-  tracing a long run degrades to a suffix window, never to back-pressure.
+* **Recording never blocks the serve path, and allocates nothing that
+  lives on.**  A record is a row of preallocated columns (kind, interned
+  name, category, track and argument names, the two timestamps, the
+  argument values) written under a (practically uncontended) lock; no
+  per-record dict or tuple outlives the call, so a traced window adds no
+  objects for the garbage collector to walk.  When the ring wraps, the
+  OLDEST spans are dropped and counted (``dropped``) — tracing a long run
+  degrades to a suffix window, never to back-pressure.  ``events()``
+  builds the record dicts once, at export.
 * **Hierarchy is time containment.**  Spans carry a track id (``tid`` —
   the thread id by default, or an explicit string track like
   ``"batch-slot-0"``); within a track, nesting is by interval
@@ -38,7 +46,13 @@ from __future__ import annotations
 import threading
 import time
 
+import numpy as np
+
 __all__ = ["Tracer", "NOOP_SPAN"]
+
+_X, _ASYNC, _INSTANT = 0, 1, 2  # record kinds, the ``_KINDS`` of the export
+_KINDS = ("X", "async", "I")
+_NARGS = 4  # arguments a record keeps in columns; more keep their dict whole
 
 
 class _NoopSpan:
@@ -103,7 +117,20 @@ class Tracer:
             raise ValueError("capacity must be >= 1")
         self.capacity = capacity
         self.annotate = annotate
-        self._ring: list = [None] * capacity
+        # one row per record; names, categories, tracks and argument-name
+        # tuples are interned into ``_atoms``, argument values and async ids
+        # sit in object columns (numpy object arrays are not gc-tracked)
+        self._kind = np.zeros(capacity, np.int8)
+        self._name = np.zeros(capacity, np.int32)
+        self._cat = np.zeros(capacity, np.int32)
+        self._tid = np.zeros(capacity, np.int32)
+        self._keys = np.zeros(capacity, np.int32)  # -1: the args dict whole
+        self._t0 = np.zeros(capacity, np.int64)
+        self._t1 = np.zeros(capacity, np.int64)
+        self._aid = np.empty(capacity, object)
+        self._vals = np.empty((capacity, _NARGS), object)
+        self._atoms: list = []
+        self._atom_id: dict = {}
         self._n = 0  # total records ever (ring cursor = _n % capacity)
         self._lock = threading.Lock()
         self.t_epoch = time.perf_counter_ns()
@@ -119,33 +146,58 @@ class Tracer:
     def now() -> int:
         return time.perf_counter_ns()
 
-    def _record(self, rec: dict) -> None:
-        with self._lock:
-            self._ring[self._n % self.capacity] = rec
-            self._n += 1
+    def _atom(self, v) -> int:
+        i = self._atom_id.get(v)
+        if i is None:
+            i = self._atom_id[v] = len(self._atoms)
+            self._atoms.append(v)
+        return i
 
-    def begin(self, name: str, **attrs) -> _LiveSpan:
-        """Open a live span on the current thread's track."""
+    def _record(self, kind: int, name: str, cat: str, tid, aid, t0: int,
+                t1: int, args: dict) -> None:
+        with self._lock:
+            i = self._n % self.capacity
+            self._n += 1
+            self._kind[i] = kind
+            self._name[i] = self._atom(name)
+            self._cat[i] = self._atom(cat)
+            self._tid[i] = self._atom(tid)
+            self._t0[i] = t0
+            self._t1[i] = t1
+            self._aid[i] = aid
+            if len(args) <= _NARGS:
+                self._keys[i] = self._atom(tuple(args))
+                for j, v in enumerate(args.values()):
+                    self._vals[i, j] = v
+            else:
+                self._keys[i] = -1
+                self._vals[i, 0] = args
+
+    def begin(self, name: str, *, cat: str = "", tid=None, t0: int | None = None,
+              **attrs) -> _LiveSpan:
+        """Open a live span on the current thread's track (or on ``tid``),
+        starting now or at ``t0`` (a ``perf_counter_ns`` stamp the caller
+        already took)."""
         ann = None
         if self._profiler is not None:
             ann = self._profiler.TraceAnnotation(name)
             ann.__enter__()
         return _LiveSpan(
-            name, attrs.pop("cat", ""), time.perf_counter_ns(),
-            attrs.pop("tid", None), attrs, ann,
+            name, cat, time.perf_counter_ns() if t0 is None else t0, tid,
+            attrs, ann,
         )
 
-    def end(self, live: _LiveSpan, **extra) -> None:
-        t1 = time.perf_counter_ns()
+    def end(self, live: _LiveSpan, *, t1: int | None = None, **extra) -> None:
+        """Close ``live`` now or at ``t1``; ``extra`` joins its args."""
+        if t1 is None:
+            t1 = time.perf_counter_ns()
         if live.ann is not None:
             live.ann.__exit__(None, None, None)
-        args = dict(live.args, **extra) if extra else live.args
-        self._record({
-            "kind": "X", "name": live.name, "cat": live.cat,
-            "t0": live.t0, "t1": t1,
-            "tid": live.tid if live.tid is not None else threading.get_ident(),
-            "args": args,
-        })
+        self._record(
+            _X, live.name, live.cat,
+            live.tid if live.tid is not None else threading.get_ident(), None,
+            live.t0, t1, dict(live.args, **extra) if extra else live.args,
+        )
 
     def span(self, name: str, **attrs) -> _SpanCM:
         """``with tracer.span("engine.compile", shape=...):`` — live span."""
@@ -154,29 +206,25 @@ class Tracer:
     def add(self, name: str, t0: int, t1: int, *, tid=None, cat: str = "",
             **attrs) -> None:
         """Retroactive complete span with explicit ns timestamps."""
-        self._record({
-            "kind": "X", "name": name, "cat": cat, "t0": int(t0), "t1": int(t1),
-            "tid": tid if tid is not None else threading.get_ident(),
-            "args": attrs,
-        })
+        self._record(
+            _X, name, cat, tid if tid is not None else threading.get_ident(),
+            None, int(t0), int(t1), attrs,
+        )
 
     def add_async(self, name: str, aid, t0: int, t1: int, *,
                   cat: str = "query", **attrs) -> None:
         """Retroactive async (overlappable) span — one ``b``/``e`` pair
         under ``id=aid`` in the Chrome export.  Same-id slices nest by
         time, so per-query phase breakdowns share the query's id."""
-        self._record({
-            "kind": "async", "name": name, "cat": cat or "async",
-            "id": aid, "t0": int(t0), "t1": int(t1), "tid": 0, "args": attrs,
-        })
+        self._record(_ASYNC, name, cat or "async", 0, aid, int(t0), int(t1),
+                     attrs)
 
     def instant(self, name: str, *, tid=None, **attrs) -> None:
         t = time.perf_counter_ns()
-        self._record({
-            "kind": "I", "name": name, "cat": "", "t0": t, "t1": t,
-            "tid": tid if tid is not None else threading.get_ident(),
-            "args": attrs,
-        })
+        self._record(
+            _INSTANT, name, "", tid if tid is not None else threading.get_ident(),
+            None, t, t, attrs,
+        )
 
     # -- inspection -----------------------------------------------------
 
@@ -186,20 +234,32 @@ class Tracer:
         return max(0, self._n - self.capacity)
 
     def events(self) -> list[dict]:
-        """Retained records, oldest first."""
+        """Retained records, oldest first, as dicts: ``kind`` ("X",
+        "async" or "I"), ``name``, ``cat``, ``t0``, ``t1``, ``tid``,
+        ``args`` and, on async records, ``id``."""
         with self._lock:
-            n = self._n
-            if n <= self.capacity:
-                out = self._ring[:n]
-            else:
-                cur = n % self.capacity
-                out = self._ring[cur:] + self._ring[:cur]
-            return list(out)
+            n, cap = self._n, self.capacity
+            rows = np.arange(n - cap, n) % cap if n > cap else np.arange(n)
+            cols = [c[rows].tolist() for c in (
+                self._kind, self._name, self._cat, self._tid, self._keys,
+                self._t0, self._t1, self._aid)]
+            vals = self._vals[rows].tolist()
+            atoms = list(self._atoms)
+        out = []
+        for kind, name, cat, tid, keys, t0, t1, aid, v in zip(*cols, vals):
+            args = v[0] if keys < 0 else dict(zip(atoms[keys], v))
+            e = {"kind": _KINDS[kind], "name": atoms[name], "cat": atoms[cat]}
+            if kind == _ASYNC:
+                e["id"] = aid
+            e.update(t0=t0, t1=t1, tid=atoms[tid], args=args)
+            out.append(e)
+        return out
 
     def clear(self) -> None:
         """Drop everything recorded so far (the warmup boundary)."""
         with self._lock:
-            self._ring = [None] * self.capacity
+            self._aid.fill(None)
+            self._vals.fill(None)
             self._n = 0
             self.t_epoch = time.perf_counter_ns()
 

@@ -9,13 +9,19 @@ trace validation, and the two contracts that make it safe to ship:
     the documented exemption: it replaced the old ad-hoc
     ``collections.Counter`` and is not part of the obs layer.
   * **Enabled is consistent** — a traced broker run still returns exact
-    answers, its Chrome trace covers every query's
-    queue→dispatch→inflight→fetch→decode lifetime, and the metrics
+    answers, its Chrome trace covers every query's lifetime (a ``query``
+    span and its ``queue`` phase, linked by ``bid`` to the batch's
+    stages), each batch's fetch stages meet end to end, and the metrics
     snapshot agrees with ``stats()``.
+  * **Recording is lean** — a record allocates nothing that outlives it,
+    so a traced window leaves the garbage collector nothing new to walk.
 """
 
 import asyncio
+import gc
+import glob
 import json
+import os
 
 import numpy as np
 import pytest
@@ -361,12 +367,27 @@ def test_enabled_broker_trace_covers_every_query(store_and_truth):
             per_query.setdefault(e["id"], set()).add(e["name"])
     assert len(per_query) == len(queries)
     for qid, names in per_query.items():
-        assert {"query", "queue", "dispatch", "inflight", "fetch",
-                "decode"} <= names, (qid, names)
+        assert names == {"query", "queue"}, (qid, names)
     batch_spans = [e for e in ch["traceEvents"]
                    if e.get("ph") == "X" and e["name"] == "broker.batch"]
     assert len(batch_spans) == st["batches"]
     assert all(0 < e["args"]["occupancy"] <= 1 for e in batch_spans)
+    # two records per query, a fixed number per batch, none per lane
+    spans = tracer.events()
+    per_batch = _stages_by_bid(spans)
+    assert len(per_batch) == st["batches"]
+    assert all(set(v) == set(BATCH_STAGES) for v in per_batch.values())
+    assert not any(e["name"] == "plan.decode_lane" for e in spans)
+    assert sum(e["kind"] == "async" for e in spans) == 2 * len(queries)
+    _assert_fetch_stages_meet(per_batch, FETCH_STAGES)
+    # broker.copy counts the bytes of the arrays the fetch brought over
+    fetched = eng.host_result(
+        b.base_plan.submit(b._encode([], b._pad_to)), unbounded=False)
+    want = [a.nbytes for a in fetched if a.nbytes]
+    assert len(want) == 5  # hit, ids, valid, count, overflow
+    for stages in per_batch.values():
+        copy = stages["broker.copy"]["args"]
+        assert (copy["bytes"], copy["arrays"]) == (sum(want), len(want))
 
     # the obs metrics snapshot agrees with the broker's reported totals
     snap = metrics.snapshot()
@@ -381,6 +402,165 @@ def test_enabled_broker_trace_covers_every_query(store_and_truth):
     profiles = b.cost_profiles()
     assert profiles["base"]["geometry"]["cap"] == 256
     assert profiles["base"].get("flops", 0) > 0
+
+
+# the batch stages of a static store, and the fetch's stages in order
+BATCH_STAGES = (
+    "broker.batch", "broker.coalesce", "broker.dispatch", "broker.inflight",
+    "broker.handoff", "broker.device_wait", "broker.copy", "broker.resume",
+    "broker.decode_deliver",
+)
+FETCH_STAGES = ("broker.handoff", "broker.device_wait", "broker.copy",
+                "broker.resume")
+
+
+def _stages_by_bid(spans) -> dict:
+    out: dict = {}
+    for e in spans:
+        if e["kind"] == "X" and e["name"].startswith("broker."):
+            stages = out.setdefault(e["args"]["bid"], {})
+            assert e["name"] not in stages, e
+            stages[e["name"]] = e
+    return out
+
+
+def _assert_fetch_stages_meet(per_batch, order):
+    """Each batch's fetch stages meet end to end, on the batch's track,
+    inside its ``broker.batch`` span."""
+    for bid, stages in per_batch.items():
+        seq = [stages[n] for n in order]
+        for a, b in zip(seq, seq[1:]):
+            assert a["t1"] == b["t0"], (bid, a["name"], b["name"])
+        assert all(a["t0"] <= a["t1"] for a in seq)
+        assert len({e["tid"] for e in stages.values()}) == 1
+        batch = stages["broker.batch"]
+        assert batch["t0"] <= seq[0]["t0"] and seq[-1]["t1"] <= batch["t1"]
+
+
+def test_dynamic_store_fetch_has_a_merge_stage(store_and_truth):
+    """With a delta to merge, the merge is a stage of its own between the
+    copy and the resume; the stages still meet end to end."""
+    from repro.core import delta
+
+    store, T, ds = store_and_truth
+    E = eng.Engine(store=delta.DynamicStore(store))
+    tracer, _ = obs.enable(ObsConfig(metrics=False))
+    s, p, o = map(int, ds.ids[0])
+
+    async def main():
+        async with ServeBroker(
+            E, ExecConfig(backend="jnp", cap=256), unbounded=False,
+            coalesce=CoalescePolicy(max_batch=8, max_delay_s=0.001),
+        ) as b:
+            b.submit_delete_nowait("t", s, p, o)
+            return await asyncio.gather(
+                b.submit_nowait("t", eng.OP_CHECK, s, p, o),
+                b.submit_nowait("t", eng.OP_ROW, s, p, 0),
+            ), b.stats()
+
+    (hit, row), st = asyncio.run(main())
+    assert hit is False and o not in set(row.tolist())
+    per_batch = _stages_by_bid(tracer.events())
+    assert len(per_batch) == st["batches"] >= 1
+    _assert_fetch_stages_meet(
+        per_batch, ("broker.handoff", "broker.device_wait", "broker.copy",
+                    "broker.merge", "broker.resume"))
+
+
+def test_device_annotations_reach_the_profile(store_and_truth, tmp_path):
+    """With ``device_annotations=True`` the fetch thread's live stages are
+    ``TraceAnnotation``s: a profile taken around a broker run holds
+    ``broker.device_wait`` and ``broker.copy`` events (and the event
+    loop's ``broker.dispatch``), which ``ProfileData`` reads."""
+    import jax
+    from jax.profiler import ProfileData
+
+    store, T, ds = store_and_truth
+    E = eng.Engine(store)
+    obs.enable(ObsConfig(metrics=False, device_annotations=True))
+
+    async def main():
+        async with ServeBroker(
+            E, ExecConfig(backend="jnp", cap=256), unbounded=False,
+            coalesce=CoalescePolicy(max_batch=8, max_delay_s=0.001),
+        ) as b:
+            return await asyncio.gather(*(
+                b.submit_nowait("t", eng.OP_CHECK, *map(int, ds.ids[i]))
+                for i in range(12)))
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        got = asyncio.run(main())
+    finally:
+        jax.profiler.stop_trace()
+    assert all(got)
+    (path,) = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                        recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events}
+    assert {"broker.dispatch", "broker.device_wait", "broker.copy"} <= names
+
+
+def test_tracer_records_allocate_nothing_that_lives_on():
+    """Recording 100,000 spans with the collector off adds fewer than
+    1,000 objects to ``gc.get_objects()``; ``events()`` still returns the
+    record dicts the readers take."""
+    t = Tracer(capacity=1 << 17)
+    was = gc.isenabled()
+    gc.collect()
+    gc.disable()
+    try:
+        n0 = len(gc.get_objects())
+        base = t.now()
+        for i in range(25_000):
+            t.add("broker.copy", base + i, base + i + 7, tid="batch-slot-1",
+                  cat="broker", bid=i, bytes=1_310_720 + i, arrays=5)
+            t.add_async("query", 10_000 + i, base, base + i,
+                        tenant=f"t{i % 8}", op=1, lane=i % 256, bid=i)
+            t.add_async("queue", 10_000 + i, base, base + i)
+            live = t.begin("broker.device_wait", tid="batch-slot-1",
+                           t0=base + i, bid=i)
+            t.end(live, t1=base + i + 3)
+        grown = len(gc.get_objects()) - n0
+    finally:
+        if was:
+            gc.enable()
+    assert grown < 1_000, grown
+    assert t.dropped == 0
+    ev = t.events()
+    assert len(ev) == 100_000
+    assert ev[-4:] == [
+        {"kind": "X", "name": "broker.copy", "cat": "broker",
+         "t0": base + 24_999, "t1": base + 25_006, "tid": "batch-slot-1",
+         "args": {"bid": 24_999, "bytes": 1_310_720 + 24_999, "arrays": 5}},
+        {"kind": "async", "name": "query", "cat": "query", "id": 34_999,
+         "t0": base, "t1": base + 24_999, "tid": 0,
+         "args": {"tenant": "t7", "op": 1, "lane": 24_999 % 256,
+                  "bid": 24_999}},
+        {"kind": "async", "name": "queue", "cat": "query", "id": 34_999,
+         "t0": base, "t1": base + 24_999, "tid": 0, "args": {}},
+        {"kind": "X", "name": "broker.device_wait", "cat": "",
+         "t0": base + 24_999, "t1": base + 25_002, "tid": "batch-slot-1",
+         "args": {"bid": 24_999}},
+    ]
+    assert all(type(e["t0"]) is int and type(e["t1"]) is int for e in ev[:8])
+
+
+def test_tracer_keeps_wide_args_and_wraps():
+    """A record with more arguments than the columns hold keeps them all;
+    a wrapped ring exports the newest records, oldest first."""
+    t = Tracer(capacity=3)
+    wide = {f"k{i}": i for i in range(7)}
+    t.add("wide", 1, 2, tid=5, **wide)
+    t.instant("mark", tid=5, note="x")
+    t.add("narrow", 3, 4, tid=5, a=1)
+    t.add("last", 5, 6, tid=5)
+    assert t.dropped == 1
+    assert [e["name"] for e in t.events()] == ["mark", "narrow", "last"]
+    t = Tracer(capacity=4)
+    t.add("wide", 1, 2, tid=5, **wide)
+    assert t.events() == [{"kind": "X", "name": "wide", "cat": "", "t0": 1,
+                           "t1": 2, "tid": 5, "args": wide}]
 
 
 def test_engine_compile_metrics_absorb_plan_cache_stats(store_and_truth):

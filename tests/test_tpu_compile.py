@@ -139,3 +139,38 @@ def test_pred_gather_dac_multilevel_compiles(one_chip):
         _vec(one_chip, 1_308_808, jnp.uint32),
         _vec(one_chip, 125_000, jnp.uint32), _vec(one_chip, 125_000),
     )
+
+
+def test_serve_step_scans_compile_under_their_names(one_chip):
+    """The serve step over the geonames shapes, with the unbounded lanes:
+    its two scan launches compile as ``k2_scan_bound`` (row and column
+    lanes) and ``k2_scan_u`` (the u-candidate scans), the names a profile
+    shows them under."""
+    from repro.core import engine
+    from repro.core.k2forest import K2Forest
+    from repro.core.predindex import PredIndex, PredIndexMeta
+    from repro.core.query import ExecConfig
+
+    pmeta = PredIndexMeta(
+        n_subjects=2_203_561, n_objects=3_031_664, n_preds=N_PREDS,
+        bytes_per_pred=1, max_degree=14, layout="dac", levels=1,
+        level_byte_start=(0,), flag_word_start=(), deg_width=4,
+        rows_per_block=32,
+    )
+    step = engine.make_serve_step(
+        META, CAP, backend=ExecConfig(backend="pallas", interpret=False),
+        pmeta=pmeta,
+    )
+    q = _s(one_chip, (256,))
+    index = PredIndex(
+        offsets=_vec(one_chip, 163_601),
+        words=_vec(one_chip, 4_287_440, jnp.uint32),
+        degs=_vec(one_chip, 654_404, jnp.uint32),
+        flags=_vec(one_chip, 1, jnp.uint32), frank=_vec(one_chip, 1),
+    )
+    text = step.lower(
+        K2Forest(*_forest(one_chip), nnz=_s(one_chip, (N_PREDS,))),
+        engine.ServeBatch(op=q, s=q, p=q, o=q), index,
+    ).compile().as_text()
+    assert "%k2_scan_bound." in text and "%k2_scan_u." in text
+    assert "%k2_scan." not in text
