@@ -64,24 +64,25 @@ def range_traverse(meta: K2Meta, cap: int, a, fpos, frb, fcb, geo, pred):
 
     n, ovf = jax.lax.fori_loop(0, r0, root, (_I32(0), _I32(0)))
 
-    def children(lvl, i, cb0, bits, m, o):
-        k, r, sub = geo[0, lvl + 1], geo[1, lvl + 1], geo[2, lvl + 1]
+    def level(lvl, k):
+        sub = geo[0, lvl + 1]
         src, dst = lvl & 1, (lvl + 1) & 1
-        rb, cb = frb[src, i], fcb[src, i]
 
-        def child(d, c):
-            m, o = c
+        def node(i, cpos, bits, m, o):
+            rb, cb = frb[src, i], fcb[src, i]
+            for d, (c, bit) in enumerate(zip(cpos, bits)):
 
-            def w(m):
-                fpos[dst, m] = cb0 + d
-                frb[dst, m] = rb + jax.lax.div(d, k) * sub
-                fcb[dst, m] = cb + jax.lax.rem(d, k) * sub
+                def w(m, c=c, d=d):
+                    fpos[dst, m] = c
+                    frb[dst, m] = rb + (d // k) * sub
+                    fcb[dst, m] = cb + (d % k) * sub
 
-            return append(cap, bits(cb0 + d), m, o, w)
+                m, o = append(cap, bit, m, o, w)
+            return m, o
 
-        return jax.lax.fori_loop(0, r, child, (m, o))
+        return list(range(k * k)), node
 
-    n, lo = descend(meta, a, fpos, geo, pred, n, children)
+    n, lo = descend(meta, a, fpos, geo, pred, n, level)
     return n, ovf | lo
 
 

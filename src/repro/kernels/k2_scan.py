@@ -126,7 +126,7 @@ def append(cap: int, bit, m, ovf, writes):
     return m + take.astype(_I32), ovf
 
 
-GEO_ROWS = 4  # per-level k, k², subside, and the lane's key digit
+GEO_ROWS = 2  # per-level subside, and the lane's key digit
 
 
 def geo_scratch():
@@ -135,44 +135,69 @@ def geo_scratch():
 
 def geo_init(meta: K2Meta, geo) -> None:
     """Per-level tree geometry into SMEM, so levels can run in a loop."""
-    for lvl, (k, sub) in enumerate(zip(meta.ks, meta.subsides)):
-        geo[0, lvl] = _I32(k)
-        geo[1, lvl] = _I32(k * k)
-        geo[2, lvl] = _I32(sub)
+    for lvl, sub in enumerate(meta.subsides):
+        geo[0, lvl] = _I32(sub)
 
 
-def descend(meta: K2Meta, a: Arena, fpos, geo, pred, n, children):
+def descend(meta: K2Meta, a: Arena, fpos, geo, pred, n, level):
     """Levels 1..H-1 of a frontier BFS.
 
     Level ``lvl + 1`` is built from the ``n`` nodes of ``fpos[lvl % 2]``:
     node i's children start at ``cb0 = level_start + (rank1(T, pos) -
-    ones_before) * k²`` and ``children(lvl, i, cb0, bits, m, ovf) -> (m,
-    ovf)`` appends the set ones, reading their bits with ``bits(cpos)``.
-    Inner levels run as one loop; the last (its bits are in L) runs after.
-    Returns ``(n, overflow)`` of the last level.
+    ones_before) * k²``.  ``level(lvl, k) -> (offs, node)`` gives the
+    offsets from ``cb0`` of the children a node reads, ascending and each
+    below k², and ``node(i, cpos, bits, m, ovf) -> (m, ovf)``, which
+    appends the set ones given their positions and bits in ``offs`` order.
+    With k² ≤ 33 (the store builds k = 4 and 2) a node's child bits lie in
+    one word and the next, read as one or two words rather than a tile
+    lookup per child.  Levels run as one loop per run of equal arity ``k``
+    (static, so a node's children unroll); the last (its bits are in L) runs
+    after.  Returns ``(n, overflow)`` of the last level.
     """
-    H = meta.n_levels
+    H, ks = meta.n_levels, meta.ks
 
-    def expand(lvl, n, tc, wc):
+    def expand(lvl, k, n, tc, wc):
         ob = a.tab.get(pred, lvl)[0]
         ls = a.tab.get(pred, lvl + 1)[1]
-        r = geo[1, lvl + 1]
         src = lvl & 1
+        if k * k > 33:
+            raise ValueError(f"arity {k}: a node's child bits span more than "
+                             "a word and the next")
+        offs, node = level(lvl, k)
+        span = offs[-1] - offs[0]
 
         def step(i, m, o):
-            cb0 = ls + (rank_at(a, pred, fpos[src, i]) - ob) * r
-            return children(lvl, i, cb0, lambda c: bit_at(tc, wc, pred, c), m, o)
+            cb0 = ls + (rank_at(a, pred, fpos[src, i]) - ob) * (k * k)
+            cpos = [cb0 + d for d in offs]
+            wi = cpos[0] >> 5
+            (w0,) = tc.get(pred, jnp.clip(wi, 0, wc - 1))
+            w1 = jax.lax.cond(
+                (cpos[0] & 31) + span >= 32,
+                lambda: tc.get(pred, jnp.clip(wi + 1, 0, wc - 1))[0],
+                lambda: w0)
+
+            def bit(c):
+                w = jnp.where((c >> 5) == wi, w0, w1)
+                return ((w >> (c & 31).astype(_U32)) & _U32(1)).astype(_I32)
+
+            return node(i, cpos, [bit(c) for c in cpos], m, o)
 
         return frontier_loop(n, step)
 
-    def inner(lvl, c):
-        n, ovf = c
-        n, lo = expand(lvl, n, a.tbit, a.wt)
-        return n, ovf | lo
+    ovf, lvl = _I32(0), 0
+    while lvl < H - 2:  # runs of inner levels whose children have arity k
+        k, end = ks[lvl + 1], lvl + 1
+        while end < H - 2 and ks[end + 1] == k:
+            end += 1
 
-    n, ovf = jax.lax.fori_loop(0, max(H - 2, 0), inner, (n, _I32(0)))
+        def inner(l, c, k=k):
+            m, o = expand(l, k, c[0], a.tbit, a.wt)
+            return m, c[1] | o
+
+        n, ovf = jax.lax.fori_loop(lvl, end, inner, (n, ovf))
+        lvl = end
     if H >= 2:
-        n, lo = expand(H - 2, n, a.lbit, a.wl)
+        n, lo = expand(H - 2, ks[H - 1], n, a.lbit, a.wl)
         ovf = ovf | lo
     return n, ovf
 
@@ -184,11 +209,11 @@ def scan_traverse(meta: K2Meta, cap: int, a: Arena, fpos, fbase, geo, pred,
     H, k0, sub0 = meta.n_levels, meta.ks[0], meta.subsides[0]
     rem = key
     for lvl, sub in enumerate(meta.subsides):
-        geo[3, lvl] = tiles.fdiv(rem, sub)
+        geo[1, lvl] = tiles.fdiv(rem, sub)
         rem = tiles.fmod(rem, sub)
 
     t0, w0 = (a.lbit, a.wl) if H == 1 else (a.tbit, a.wt)
-    d0 = geo[3, 0]
+    d0 = geo[1, 0]
 
     def root(j, n):
         p0 = jnp.where(is_row, d0 * k0 + j, j * k0 + d0)
@@ -198,24 +223,25 @@ def scan_traverse(meta: K2Meta, cap: int, a: Arena, fpos, fbase, geo, pred,
 
     n = jax.lax.fori_loop(0, min(k0, cap), root, _I32(0))
 
-    def children(lvl, i, cb0, bits, m, o):
-        k, sub, d = geo[0, lvl + 1], geo[2, lvl + 1], geo[3, lvl + 1]
+    def level(lvl, k):
+        sub, d = geo[0, lvl + 1], geo[1, lvl + 1]
         src, dst = lvl & 1, (lvl + 1) & 1
-        base = fbase[src, i]
+        lo, stride = jnp.where(is_row, d * k, d), jnp.where(is_row, 1, k)
 
-        def child(ch, c):
-            m, o = c
-            cpos = cb0 + jnp.where(is_row, d * k + ch, ch * k + d)
+        def node(i, cpos, bits, m, o):
+            base = fbase[src, i]
+            for ch, (c, b) in enumerate(zip(cpos, bits)):
 
-            def w(m):
-                fpos[dst, m] = cpos
-                fbase[dst, m] = base + ch * sub
+                def w(m, c=c, ch=ch):
+                    fpos[dst, m] = c
+                    fbase[dst, m] = base + ch * sub
 
-            return append(cap, bits(cpos), m, o, w)
+                m, o = append(cap, b, m, o, w)
+            return m, o
 
-        return jax.lax.fori_loop(0, k, child, (m, o))
+        return [lo + ch * stride for ch in range(k)], node
 
-    n, ovf = descend(meta, a, fpos, geo, pred, n, children)
+    n, ovf = descend(meta, a, fpos, geo, pred, n, level)
     return n, ovf | _I32(k0 > cap)
 
 

@@ -6,7 +6,11 @@ at real scale they are far beyond VMEM (the geonames Table-1 forest is a
 traversal kernels therefore run on the TPU's scalar core.  A read of word
 ``a[r, c]`` DMAs the aligned ``(8, 128)`` tile that holds it into an SMEM
 buffer and keeps it there: further reads that fall in the held tile cost one
-SMEM load.  A frontier walks a level in position order, so most reads hit.
+SMEM load.  A frontier walks a level in position order, but a k²-tree scan's
+level does not stay in a few tiles: it fans out to a few hundred nodes over
+about a hundred tiles (~113 rank-tile and ~134 bit-tile fetches per geonames
+scanning lane, PERF.md §5), so consecutive nodes often share a tile and a
+level as a whole does not.
 
 The store builds every array in whole tiles (``core/bitvec.py``), so each
 tile DMA is in bounds on the chip and in interpret mode alike; the kernels

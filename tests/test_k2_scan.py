@@ -278,3 +278,170 @@ def test_k2_scan_mixed_axes_one_batch():
         truth = scan_truth(dense, int(keys[i]), int(axes[i]))
         got = np.asarray(r.ids[i])[np.asarray(r.valid[i])]
         assert (got == truth).all()
+
+
+# ---------------------------------------------------------------------------
+# Wide levels: frontiers that fan out over many tiles of the arena
+# ---------------------------------------------------------------------------
+
+WIDE_SIDE = 1 << 12  # ks (4, 4, 4, 4, 4, 2, 2): five dense k = 4 levels
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """A side-2^12 forest whose scans fan out over tens of tiles a level
+    (predicate 0: 40,000 cells), beside a sparse tree and an empty one."""
+    rng = np.random.default_rng(21)
+    coords = [(rng.integers(0, WIDE_SIDE, n), rng.integers(0, WIDE_SIDE, n))
+              for n in (40_000, 300, 0)]
+    meta, f = _forest(coords, WIDE_SIDE)
+    return coords, meta, f
+
+
+def _wide_lanes(q: int, live: int, seed: int):
+    """``q`` lanes, ``live`` of them on the dense tree or the sparse or empty
+    one, spread over the batch and ending on a live lane; the rest dead."""
+    rng = np.random.default_rng(seed)
+    preds = np.full(q, -1, np.int32)
+    at = np.append(rng.choice(q - 1, live - 1, replace=False), q - 1)
+    preds[at] = rng.choice([0, 0, 0, 1, 2], live)
+    keys = rng.integers(0, WIDE_SIDE, q).astype(np.int32)
+    axes = rng.integers(0, 2, q).astype(np.int32)
+    return preds, keys, axes
+
+
+def _line_truth(coords, pred: int, key: int, axis: int) -> np.ndarray:
+    rows, cols = coords[pred]
+    hit = (rows == key) if axis == 0 else (cols == key)
+    return np.unique((cols if axis == 0 else rows)[hit]).astype(np.int32)
+
+
+def level_tiles(meta, f, pred: int, key: int, axis: int) -> int:
+    """The most (8, 128) tiles of ``t_words`` that one inner level of a
+    lane's scan reads ranks from, by a numpy walk of its whole frontier."""
+    tw, tr = np.asarray(f.t_words), np.asarray(f.t_rank)
+    ob, ls = np.asarray(f.ones_before), np.asarray(f.level_start)
+    ks, row = meta.ks, axis == 0
+    digits, rem = [], key
+    for sub in meta.subsides:
+        digits.append(rem // sub)
+        rem %= sub
+
+    def bit(pos):
+        return (int(tw[pred, pos >> 5]) >> (pos & 31)) & 1
+
+    front = [p for p in (digits[0] * ks[0] + j if row else j * ks[0] + digits[0]
+                         for j in range(ks[0])) if bit(p)]
+    widest = 0
+    for lvl in range(meta.n_levels - 2):
+        widest = max(widest, len({pos >> 12 for pos in front}))  # word >> 7
+        k, d, nxt = ks[lvl + 1], digits[lvl + 1], []
+        for pos in front:
+            w = pos >> 5
+            rank = int(tr[pred, w]) + bin(
+                int(tw[pred, w]) & ((1 << (pos & 31)) - 1)).count("1")
+            cb0 = int(ls[pred, lvl + 1]) + (rank - int(ob[pred, lvl])) * k * k
+            nxt += [c for c in (cb0 + (d * k + ch if row else ch * k + d)
+                                for ch in range(k)) if bit(c)]
+        front = nxt
+    return widest
+
+
+WIDE_CASES = {
+    # one level's reads meet tens of tiles
+    "many_tiles": dict(q=12, live=10, cap=1024),
+    # cap 64 overflows mid-level
+    "overflow": dict(q=12, live=10, cap=64),
+    # two 1024-lane grid steps, dead lanes among live ones, cap below k0
+    "two_blocks_cap_below_root": dict(q=1100, live=16, cap=2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_CASES))
+def test_k2_scan_wide_levels(wide, case):
+    """Scans whose levels fan out over many tiles of the arena: bit-exact
+    against both jnp traversals and the numpy truth."""
+    coords, meta, f = wide
+    c = WIDE_CASES[case]
+    cap = c["cap"]
+    preds, keys, axes = _wide_lanes(c["q"], c["live"], seed=len(case))
+    r = _run_all_backends(meta, f, preds, keys, axes, cap)
+    for i in np.flatnonzero(preds >= 0):
+        assert_scan_result(
+            r.ids[i], r.valid[i], r.count[i], r.overflow[i],
+            _line_truth(coords, preds[i], keys[i], axes[i]), cap,
+            label=f"{case} lane {i}")
+    assert not np.asarray(r.valid)[preds < 0].any()
+    ovf = np.asarray(r.overflow)
+    if case == "many_tiles":
+        assert max(level_tiles(meta, f, int(p), int(k), int(x))
+                   for p, k, x in zip(preds, keys, axes) if p == 0) >= 10
+        assert not ovf.any()
+    else:
+        assert ovf.any()
+
+
+@pytest.mark.parametrize("cap_y", [16, 1024])
+def test_k2_scan_rebind_wide_levels(wide, cap_y):
+    """The fused scan → rebind takes the same walk: bit-exact against the
+    jnp composition on the wide forest, with and without Y overflow."""
+    _, meta, f = wide
+    rng = np.random.default_rng(cap_y)
+    q = 4
+    args = (np.array([0, 1, -1, 0]), rng.integers(0, WIDE_SIDE, q),
+            rng.integers(0, 2, q), np.array([0, 0, 0, 1]),
+            rng.integers(0, 2, q))
+    o_pl = k2forest.scan_rebind_batch(meta, f, *args, 8, cap_y, "pallas")
+    o_j = k2forest.scan_rebind_batch(meta, f, *args, 8, cap_y, "jnp")
+    assert_results_identical(tuple(o_pl), tuple(o_j), "rebind pallas-vs-jnp")
+    assert int(np.asarray(o_pl[2]).max()) > 1
+
+
+@pytest.mark.parametrize("cap", [64, 1024])
+def test_k2_range_wide_levels(wide, cap):
+    """The full-matrix walk shares the scan's level loop: bit-exact against
+    the jnp traversal and reference on the wide forest, overflowing on the
+    dense tree at either cap and whole on the sparse tree at 1024."""
+    coords, meta, f = wide
+    preds = jnp.asarray([0, 1, -1, 2], jnp.int32)
+    r = k2forest.range_scan_batch(meta, f, preds, cap, backend="pallas")
+    r_jnp = k2forest.range_scan_batch(meta, f, preds, cap, backend="jnp")
+    r_ref = ref.k2_range_ref(meta, preds, f.t_words, f.t_rank, f.l_words,
+                             f.ones_before, f.level_start, cap=cap)
+    assert_results_identical(tuple(r), tuple(r_jnp), "range pallas-vs-jnp")
+    assert_results_identical(tuple(r), tuple(r_ref), "range pallas-vs-ref")
+    count, ovf = np.asarray(r.count), np.asarray(r.overflow)
+    sparse = len(set(zip(*coords[1])))
+    assert ovf[0] and count[0] == cap
+    assert ovf[1] == (cap < sparse) and count[1] == min(cap, sparse)
+    assert count[2] == 0 and count[3] == 0 and not ovf[2:].any()
+
+
+@pytest.mark.parametrize("mode", ["eager", "on_wait"])
+def test_kernels_wait_on_every_dma(wide, mode, capsys):
+    """Under the TPU interpreter, each kernel that reads arena tiles and
+    writes records by DMA answers as the default interpreter does
+    when a DMA lands only once it is waited on (a read before its wait
+    would see a stale tile), and leaves no DMA outstanding at exit when
+    each lands as it starts (the interpreter reports a semaphore left
+    counting)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels import k2_range, k2_scan
+
+    _, meta, f = wide
+    arrs = (f.t_words, f.t_rank, f.l_words, f.ones_before, f.level_start)
+    how = pltpu.InterpretParams(dma_execution_mode=mode)
+    # a column of the dense tree (many tiles a level), a dead lane, and
+    # a row of the sparse tree; cap 64 and 16 overflow mid-level
+    p, k, x = (jnp.asarray(v, jnp.int32) for v in ([0, -1, 1], [77, 5, 300],
+                                                   [1, 0, 0]))
+    for run in (
+        lambda i: k2_scan.k2_scan(meta, p, k, x, *arrs, cap=64, interpret=i),
+        lambda i: k2_scan.k2_scan_rebind(meta, p, k, x, p * 0 + 1, x, *arrs,
+                                         cap_x=2, cap_y=16, interpret=i),
+        lambda i: k2_range.k2_range(meta, jnp.asarray([1, -1, 2]), *arrs,
+                                    cap=16, interpret=i),
+    ):
+        assert_results_identical(tuple(run(how)), tuple(run(True)), mode)
+    assert "at kernel exit" not in capsys.readouterr().out
